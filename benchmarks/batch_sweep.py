@@ -48,7 +48,7 @@ from benchmarks.common import (dataset, nsg_index, time_batched,
 from benchmarks.dist_backend import _row_key
 from repro.ann import SearchParams
 from repro.core import recall_at_k
-from repro.kernels import ops as kops
+from repro.kernels.platform import interprets
 
 K = 10
 BATCHES = (1, 8, 64, 256)
@@ -69,19 +69,7 @@ def sweep(out_path: str = "BENCH_dist_backend.json",
     rows = []
     for backend in backends:
         fn = index.searcher(PARAMS.with_(backend=backend))
-        run_batches = batches
-        if backend.startswith("dedup") and kops.INTERPRET:
-            # the dedup kernel trades gathers for a (uniques x B) reduce
-            # grid — free on the MXU, but interpret-mode emulation walks it
-            # cell by cell, so wall clock scales ~B^2; cap the sweep where
-            # emulation stays tractable (a TPU session lifts this)
-            run_batches = tuple(b for b in batches if b <= 64)
-            dropped = tuple(b for b in batches if b > 64)
-            if dropped:
-                print(f"bench_batch_{backend}: skipping B={dropped} "
-                      "(interpret-mode emulation; run compiled for full "
-                      "range)")
-        for bsz in run_batches:
+        for bsz in batches:
             queries = jnp.asarray(ds.queries[:bsz])
             ids, _, stats = fn(queries)
             us = time_batched(fn, queries)
@@ -98,7 +86,7 @@ def sweep(out_path: str = "BENCH_dist_backend.json",
                 "backend": backend,
                 "batch": bsz,
                 "host": host,
-                "interpret": bool(kops.INTERPRET),
+                "interpret": interprets(),
                 "n": n,
                 "q": bsz,
                 "unix_time": time.time(),

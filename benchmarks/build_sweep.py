@@ -49,7 +49,7 @@ from benchmarks.common import K, dataset, write_trajectory
 from repro.core import build_nsg, build_nsg_serial, recall_at_k
 from repro.core.bfis import search_topm_batch
 from repro.core.config import SearchConfig
-from repro.kernels import ops as kops
+from repro.kernels.platform import interprets
 
 BATCHES = (1, 8, 32, 128)
 BACKENDS = ("ref",)
@@ -90,7 +90,7 @@ def sweep(out_path: str = "BENCH_build.json",
     ds = dataset(n=n, q=64)
     data = np.asarray(ds.base, np.float32)
     host = platform.node() or platform.machine()
-    base = {"n": n, "host": host, "interpret": bool(kops.INTERPRET),
+    base = {"n": n, "host": host, "interpret": interprets(),
             "degree": DEGREE, "ef": EF, "passes": PASSES}
 
     def _serial():

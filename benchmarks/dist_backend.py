@@ -30,7 +30,7 @@ from benchmarks.common import (dataset, nsg_index, time_batched,
 from repro.ann import SearchParams
 from repro.core import recall_at_k
 from repro.kernels import available_backends
-from repro.kernels import ops as kops
+from repro.kernels.platform import interprets
 from repro.quant.scheme import required_quant_dtype
 
 K = 10
@@ -98,7 +98,7 @@ def sweep(out_path: str = "BENCH_dist_backend.json", n: int = 2000,
                 "quant": quant,
                 "rerank_k": rerank_k,
                 "host": host,
-                "interpret": bool(kops.INTERPRET),
+                "interpret": interprets(),
                 # dataset scale rides on every row: rows from sweeps with
                 # different configs coexist in the trajectory, so the
                 # top-level "config" (latest run) must not be trusted per row

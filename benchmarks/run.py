@@ -46,6 +46,8 @@ def main() -> None:
     ap.add_argument("--build-out", default="BENCH_build.json",
                     help="output path for --sweep-build")
     args = ap.parse_args()
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
 
     if args.sweep_backends:
         from benchmarks import dist_backend

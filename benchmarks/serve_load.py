@@ -51,7 +51,7 @@ import numpy as np
 
 from benchmarks.common import dataset, merge_trajectory_rows, nsg_index
 from repro.ann import SearchParams
-from repro.kernels import ops as kops
+from repro.kernels.platform import interprets
 from repro.serve import (AdmissionPolicy, AdmissionRejected, CachePolicy,
                          ReplicaRouter, RouterPolicy)
 from repro.serve.coalescer import DeadlineExceeded
@@ -233,7 +233,7 @@ def sweep(out_path: str = "BENCH_serve.json", n: int = 2000, q: int = 32,
             "quant": "none",
             "algorithm": params.algorithm,
             "host": host,
-            "interpret": bool(kops.INTERPRET),
+            "interpret": interprets(),
             "n": n,
             "k": K,
             "max_batch": srv.policy.max_batch,

@@ -17,9 +17,11 @@ import numpy as np
 from repro.ann import AnnIndex, IndexSpec, SearchParams
 from repro.core import recall_at_k
 from repro.data import make_vector_dataset
+from repro.launch.compile_cache import use_compile_cache
 
 
 def main():
+    use_compile_cache()
     print("== Speed-ANN quickstart (AnnIndex facade) ==")
     ds = make_vector_dataset("sift", n=5000, n_queries=32, k=10, dim=32)
     print(f"dataset: {ds.base.shape[0]} points, d={ds.base.shape[1]}")

@@ -45,6 +45,7 @@ import numpy as np
 from repro.ann import AnnIndex, IndexSpec, SearchParams
 from repro.data import make_vector_dataset
 from repro.kernels.registry import available_backends
+from repro.launch.compile_cache import use_compile_cache
 
 
 def main():
@@ -99,6 +100,7 @@ def main():
                          "ui.perfetto.dev); also prints the metrics "
                          "registry (docs/observability.md)")
     args = ap.parse_args()
+    use_compile_cache()
 
     print("== Speed-ANN serving driver ==")
     ds = make_vector_dataset("deep", n=args.n, n_queries=args.max_batch,

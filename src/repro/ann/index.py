@@ -57,6 +57,7 @@ from repro.core.build import (HNSWIndex, build_hnsw, build_nsg, exact_knn,
 from repro.core.graph import (PaddedCSR, compute_medoid, group_by_indegree,
                               remap_sentinels)
 from repro.core.speedann import search_speedann_batch
+from repro.kernels.registry import with_kernel_tables
 from repro.quant import codec as quant_codec
 from repro.quant.scheme import required_quant_dtype
 
@@ -155,7 +156,8 @@ def quantize_graph(graph: PaddedCSR, quant) -> PaddedCSR:
     scales = quant_codec.fit_scales(graph.vectors, quant)
     codes = quant_codec.quantize(graph.vectors, quant, scales)
     graph = graph._replace(codes=codes,
-                           scales=jnp.asarray(scales, jnp.float32))
+                           scales=jnp.asarray(scales, jnp.float32),
+                           code_tiles=None)
     if not quant.keep_float:
         vectors = quant_codec.dequantize(codes, quant, graph.scales)
         flat = graph.flat
@@ -163,7 +165,8 @@ def quantize_graph(graph: PaddedCSR, quant) -> PaddedCSR:
             from repro.core.graph import _flatten_top
             flat = jnp.asarray(_flatten_top(
                 np.asarray(graph.nbrs), np.asarray(vectors), graph.n_top))
-        graph = graph._replace(vectors=vectors, flat=flat)
+        graph = graph._replace(vectors=vectors, flat=flat,
+                               vector_tiles=None)
     return graph
 
 
@@ -199,6 +202,7 @@ class AnnIndex:
                       if self.tombstone is not None
                       else jnp.zeros((0,), jnp.bool_))
         self._searcher_cache: Dict = {}
+        self._kernel_graph: Optional[PaddedCSR] = None   # see _graph_for
         self._host_vectors: Optional[np.ndarray] = None  # exact() cache
 
     # -- introspection -----------------------------------------------------
@@ -284,7 +288,18 @@ class AnnIndex:
     def _invalidate(self) -> None:
         """Drop every cache derived from the graph arrays (after mutation)."""
         self._searcher_cache = {}
+        self._kernel_graph = None
         self._host_vectors = None
+
+    def _graph_for(self, backend: str) -> PaddedCSR:
+        """The graph a ``backend`` searcher reads: ``self.graph`` plus the
+        row-tiled table that backend's Pallas kernel gathers from, laid out
+        once per graph and shared by every searcher (nothing extra for the
+        ``ref`` backends)."""
+        if self._kernel_graph is None:
+            self._kernel_graph = self.graph
+        self._kernel_graph = with_kernel_tables(self._kernel_graph, backend)
+        return self._kernel_graph
 
     def add(self, new_vectors) -> np.ndarray:
         """Insert new vectors into the live index without a rebuild.
@@ -633,10 +648,11 @@ class AnnIndex:
             raise ValueError(algorithm)
 
         @jax.jit
-        def jitted(nbrs, vectors, medoid, flat, codes, scales, ofn_arr,
-                   tomb_arr, q):
+        def jitted(nbrs, vectors, medoid, flat, codes, scales, vector_tiles,
+                   code_tiles, ofn_arr, tomb_arr, q):
             g = PaddedCSR(nbrs=nbrs, vectors=vectors, medoid=medoid,
-                          n_top=n_top, flat=flat, codes=codes, scales=scales)
+                          n_top=n_top, flat=flat, codes=codes, scales=scales,
+                          vector_tiles=vector_tiles, code_tiles=code_tiles)
             q = q.astype(jnp.float32)
             if normalize:
                 q = normalize_queries(q)
@@ -663,14 +679,15 @@ class AnnIndex:
                 ids = remap_result_ids(ids, ofn_arr, n_nodes)
             return ids, dists, stats
 
-        graph = self.graph
+        graph = self._graph_for(params.backend)
 
         def fn(queries) -> SearchResult:
             q = jnp.asarray(queries)
             if q.ndim != 2:
                 raise ValueError(f"queries must be (B, d), got {q.shape}")
             out = jitted(graph.nbrs, graph.vectors, graph.medoid,
-                         graph.flat, graph.codes, graph.scales, ofn, tomb,
+                         graph.flat, graph.codes, graph.scales,
+                         graph.vector_tiles, graph.code_tiles, ofn, tomb,
                          q)
             return SearchResult(*out)
 

@@ -42,6 +42,7 @@ import numpy as np
 
 from repro.core.config import SearchConfig
 from repro.core.graph import PaddedCSR, compute_medoid, make_padded_csr
+from repro.kernels.platform import row_tiles
 
 
 # ---------------------------------------------------------------------------
@@ -57,14 +58,19 @@ def normalize_rows(x: np.ndarray) -> np.ndarray:
 @functools.partial(jax.jit, static_argnames=("metric",))
 def _dist_block(q: jax.Array, x: jax.Array, metric: str = "l2") -> jax.Array:
     """(B, N) distances between query block and data block; smaller =
-    closer for every metric ("ip" = negative inner product)."""
+    closer for every metric ("ip" = negative inner product).
+
+    The product runs at full f32 precision: a TPU's default f32 matmul
+    rounds its inputs to bf16, which reorders near neighbours and would
+    make this "exact" ground truth approximate."""
     q = q.astype(jnp.float32)
     x = x.astype(jnp.float32)
+    qx = jnp.matmul(q, x.T, precision=jax.lax.Precision.HIGHEST)
     if metric == "ip":
-        return -(q @ x.T)
+        return -qx
     q2 = jnp.sum(q * q, axis=1, keepdims=True)
     x2 = jnp.sum(x * x, axis=1)
-    return q2 + x2[None, :] - 2.0 * (q @ x.T)
+    return q2 + x2[None, :] - 2.0 * qx
 
 
 def exact_knn(
@@ -229,6 +235,7 @@ def _build_search_config(ef: int, metric: str, backend: str) -> SearchConfig:
 
 @functools.partial(jax.jit, static_argnames=("cfg", "pool"))
 def _candidate_search_batch(nbrs: jax.Array, vectors: jax.Array,
+                            vector_tiles: Optional[jax.Array],
                             entry: jax.Array, queries: jax.Array,
                             cfg: SearchConfig, pool: str) -> jax.Array:
     """Candidate beam search for a B-leading query batch over a graph
@@ -251,7 +258,7 @@ def _candidate_search_batch(nbrs: jax.Array, vectors: jax.Array,
     graph = PaddedCSR(
         nbrs=nbrs, vectors=vectors, medoid=entry, n_top=0,
         flat=jnp.zeros((0, nbrs.shape[1], vectors.shape[1]),
-                       vectors.dtype))
+                       vectors.dtype), vector_tiles=vector_tiles)
     if pool == "visited":
         _, _, _, visited = search_topm_batch_visited(graph, queries, cfg)
         return visited
@@ -259,6 +266,15 @@ def _candidate_search_batch(nbrs: jax.Array, vectors: jax.Array,
         ids, _, _ = search_topm_batch(graph, queries, cfg)
         return ids
     raise ValueError(f"unknown candidate pool {pool!r}")
+
+
+def _device_tables(data: np.ndarray, build_backend: str
+                   ) -> Tuple[jax.Array, Optional[jax.Array]]:
+    """The build's device tables: the (N, d) vectors, plus their row-tiled
+    copy when ``build_backend`` is a Pallas kernel (None for ``ref``)."""
+    vectors = jnp.asarray(data)
+    return vectors, (None if build_backend == "ref"
+                     else row_tiles(vectors))
 
 
 def _visited_to_rows(vis: np.ndarray, n: int) -> np.ndarray:
@@ -277,7 +293,8 @@ def _visited_to_rows(vis: np.ndarray, n: int) -> np.ndarray:
 
 
 def _search_candidates(
-    nbrs_dev: jax.Array, vectors_dev: jax.Array, entry_dev: jax.Array,
+    nbrs_dev: jax.Array, vectors_dev: jax.Array,
+    tiles_dev: Optional[jax.Array], entry_dev: jax.Array,
     queries: np.ndarray, cfg: SearchConfig, build_batch: int,
     batch_perm: Optional[int] = None, pool: str = "visited",
 ) -> np.ndarray:
@@ -304,15 +321,15 @@ def _search_candidates(
             perm = np.random.RandomState(batch_perm + s).permutation(
                 build_batch)
             res = np.asarray(_candidate_search_batch(
-                nbrs_dev, vectors_dev, entry_dev, jnp.asarray(q[perm]),
-                cfg, pool))
+                nbrs_dev, vectors_dev, tiles_dev, entry_dev,
+                jnp.asarray(q[perm]), cfg, pool))
             unperm = np.empty_like(res)
             unperm[perm] = res
             res = unperm
         else:
             res = np.asarray(_candidate_search_batch(
-                nbrs_dev, vectors_dev, entry_dev, jnp.asarray(q), cfg,
-                pool))
+                nbrs_dev, vectors_dev, tiles_dev, entry_dev, jnp.asarray(q),
+                cfg, pool))
         out.append(_visited_to_rows(res[:b], n)
                    if pool == "visited" else res[:b].astype(np.int32))
     width = max(c.shape[1] for c in out)
@@ -470,7 +487,7 @@ def insert_points(
     new_ids = np.asarray(new_ids, np.int64)
     n = data.shape[0]
     cfg = _build_search_config(ef, metric, build_backend)
-    vectors_dev = jnp.asarray(data)
+    vectors_dev, tiles_dev = _device_tables(data, build_backend)
     entry_dev = jnp.asarray(entry, jnp.int32)
 
     pos = 0
@@ -480,7 +497,7 @@ def insert_points(
         pos, inserted = 1, 1
     while pos < new_ids.shape[0]:
         take = min(inserted, new_ids.shape[0] - pos)
-        _process_round(nbrs, data, vectors_dev, entry_dev,
+        _process_round(nbrs, data, vectors_dev, tiles_dev, entry_dev,
                        new_ids[pos:pos + take], cfg, degree, alpha, metric,
                        build_batch, serial, batch_perm)
         pos += take
@@ -489,7 +506,8 @@ def insert_points(
 
 def _process_round(
     nbrs: np.ndarray, data: np.ndarray, vectors_dev: jax.Array,
-    entry_dev: jax.Array, round_ids: np.ndarray, cfg: SearchConfig,
+    tiles_dev: Optional[jax.Array], entry_dev: jax.Array,
+    round_ids: np.ndarray, cfg: SearchConfig,
     degree: int, alpha: float, metric: str, build_batch: int,
     serial: bool, batch_perm: Optional[int], pool: str = "visited",
 ) -> None:
@@ -498,8 +516,8 @@ def _process_round(
     rows, then run the deterministic reverse pass."""
     n = data.shape[0]
     vis = _search_candidates(
-        jnp.asarray(nbrs), vectors_dev, entry_dev, data[round_ids], cfg,
-        build_batch, batch_perm, pool)
+        jnp.asarray(nbrs), vectors_dev, tiles_dev, entry_dev,
+        data[round_ids], cfg, build_batch, batch_perm, pool)
     cand = _canonical_candidates(vis, nbrs[round_ids], round_ids, n)
     pruned = _prune_round(data, round_ids, cand, degree, alpha, metric,
                           serial)
@@ -533,12 +551,12 @@ def _refine_pass(
     The narrow pool polishes local structure while incumbent long edges
     keep their slots."""
     cfg = _build_search_config(ef, metric, build_backend)
-    vectors_dev = jnp.asarray(data)
+    vectors_dev, tiles_dev = _device_tables(data, build_backend)
     entry_dev = jnp.asarray(entry, jnp.int32)
     pos, step = 0, 1
     while pos < order.shape[0]:
         take = min(step, order.shape[0] - pos)
-        _process_round(nbrs, data, vectors_dev, entry_dev,
+        _process_round(nbrs, data, vectors_dev, tiles_dev, entry_dev,
                        order[pos:pos + take], cfg, degree, alpha, metric,
                        build_batch, serial, batch_perm, pool="results")
         pos += take
@@ -563,7 +581,8 @@ def build_nsg(
     (medoid-first random order) plus ``passes - 1`` synchronous α-pruned
     refinement passes.  The insertion pass prunes with α=1 when refinement
     follows (the seed builder's schedule); a single-pass build prunes with
-    ``alpha`` directly.
+    ``alpha`` directly.  Last, NSG's connectivity step
+    (:func:`connect_from`) links every vertex the medoid cannot reach.
 
     ``metric``: "l2" (default), "ip" (MIPS graph — ip-NSW-style pruning on
     negative-inner-product distances), or "cosine" (the base vectors are
@@ -600,7 +619,83 @@ def build_nsg(
     insert_points(nbrs, data, medoid, order, 0, alpha=a_ins, **kw)
     for _ in range(max(passes - 1, 0)):
         _refine_pass(nbrs, data, medoid, order, alpha=alpha, **kw)
+    connect_from(nbrs, data, medoid, ef=ef_construction, metric=metric,
+                 build_backend=build_backend)
     return make_padded_csr(nbrs, data, medoid=medoid)
+
+
+def reachable(nbrs: np.ndarray, roots, reached: Optional[np.ndarray] = None
+              ) -> np.ndarray:
+    """(N,) bool: the vertices reachable from ``roots`` along out-edges
+    (breadth-first over the padded adjacency), grown in place from
+    ``reached`` when given."""
+    n = nbrs.shape[0]
+    reached = np.zeros(n, bool) if reached is None else reached
+    frontier = np.unique(np.asarray(roots, np.int64))
+    frontier = frontier[~reached[frontier]]
+    while frontier.size:
+        reached[frontier] = True
+        nxt = nbrs[frontier].reshape(-1)
+        nxt = nxt[nxt < n]
+        frontier = np.unique(nxt[~reached[nxt]])
+    return reached
+
+
+def connect_from(nbrs: np.ndarray, data: np.ndarray, root: int, *, ef: int,
+                 metric: str, build_backend: str = "ref") -> int:
+    """NSG's connectivity step (Fu et al. 2019, "tree spanning"): make every
+    vertex reachable from ``root``, in place.
+
+    While some vertex is unreached, take the lowest unreached id v, search
+    the current graph for v's vector from ``root``, and link the nearest
+    REACHED vertex u the search returned to v (u = ``root`` when it found
+    none); everything v reaches becomes reached with it.  The link goes
+    into a padding slot of the nearest such u that has one; when every
+    row the search returned is full, it replaces, in the nearest u's row,
+    the edge to the neighbour with the largest in-degree — never an
+    earlier link.  A replaced edge may have been some vertex's only way
+    in, so a final sweep re-checks reachability from ``root``.
+    Deterministic.  Returns the number of links added.
+
+    Degree-capped pruning can leave whole regions without an in-edge from
+    the rest of the graph — on well-separated clusters every vertex's
+    slots fill inside its own cluster — and a search from the single entry
+    can then never reach them."""
+    n = nbrs.shape[0]
+    cfg = _build_search_config(ef, metric, build_backend)
+    vectors_dev, tiles_dev = _device_tables(data, build_backend)
+    root_dev = jnp.asarray(root, jnp.int32)
+    indeg = np.bincount(nbrs[nbrs < n], minlength=n)
+    linked = np.zeros(nbrs.shape, bool)         # slots holding a link
+    reached = reachable(nbrs, [root])
+    while not reached.all():
+        while not reached.all():
+            v = int(np.argmin(reached))
+            found = _search_candidates(
+                jnp.asarray(nbrs), vectors_dev, tiles_dev, root_dev,
+                data[v:v + 1], cfg, 1, pool="results")[0]
+            near = found[found < n]
+            near = near[reached[near]]
+            has_free = (nbrs[near] >= n).any(axis=1)
+            if has_free.any():
+                u = int(near[np.argmax(has_free)])
+            else:
+                u = next((int(x) for x in np.concatenate(
+                    [near, np.flatnonzero(reached)])
+                    if not linked[x].all()), root)
+            row = nbrs[u]
+            free = np.flatnonzero(row >= n)
+            if free.size:
+                slot = int(free[0])
+            else:
+                slot = int(np.argmax(np.where(linked[u], -1, indeg[row])))
+                indeg[row[slot]] -= 1
+            row[slot] = v
+            linked[u, slot] = True
+            indeg[v] += 1
+            reachable(nbrs, [v], reached)
+        reached = reachable(nbrs, [root])
+    return int(linked.sum())
 
 
 def build_nsg_serial(

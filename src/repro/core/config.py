@@ -36,11 +36,11 @@ class SearchConfig:
     hash_bits: int = 14          # hash-set capacity = 2**hash_bits
     # distance backend for the neighbor-expansion hot path; resolved through
     # repro.kernels.registry:  "ref" (pure-jnp gather), "rowgather"
-    # (scalar-prefetch Pallas row gather), "dma" (explicit-DMA tile gather +
-    # MXU reduction).  Backends are BATCH-MAJOR: one kernel launch covers the
+    # (scalar-prefetch Pallas row gather), "dma" (explicit-DMA tile
+    # gather).  Backends are BATCH-MAJOR: one kernel launch covers the
     # whole (B, M, R) expansion of a query batch per global step.  Pallas
     # backends run in interpret mode on CPU and lower through Mosaic on TPU
-    # (see kernels/ops.INTERPRET).
+    # (see kernels/platform.pallas_call).
     dist_backend: str = "ref"
     dma_group: int = 8           # G: rows per DMA tile ("dma" backend only)
     # distributed search: static outer (scatter/merge) round budget — bounded

@@ -33,40 +33,7 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
-
-# --- jax version compat: shard_map location + replication-check kwarg ------
-try:                                      # jax >= 0.5 exports it at top level
-    from jax import shard_map as _shard_map
-    _SHARD_MAP_KW = {"check_vma": False}
-except ImportError:                       # 0.4.x: experimental namespace
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SHARD_MAP_KW = {"check_rep": False}
-
-
-def shard_map(f, mesh, in_specs, out_specs):
-    """Version-portable ``shard_map`` with replication checking off (stats
-    leaves are reduced to uniform values manually)."""
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **_SHARD_MAP_KW)
-
-
-def make_search_mesh(shape, names=("data", "model")) -> Mesh:
-    """Version-portable mesh construction for the search meshes: newer jax
-    wants explicit ``axis_types``, 0.4.35+ has ``jax.make_mesh`` without
-    that parameter, and older jax only has the raw ``Mesh`` constructor."""
-    shape, names = tuple(shape), tuple(names)
-    if hasattr(jax, "make_mesh"):
-        try:
-            axis_types = (jax.sharding.AxisType.Auto,) * len(names)
-            return jax.make_mesh(shape, names, axis_types=axis_types)
-        except (AttributeError, TypeError):
-            return jax.make_mesh(shape, names)
-    n = 1
-    for s in shape:
-        n *= s
-    devices = np.asarray(jax.devices()[:n]).reshape(shape)
-    return Mesh(devices, names)
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.config import SearchConfig
 from repro.core import queue as fq
@@ -75,6 +42,22 @@ from repro.core.bfis import (DistFn, expand, point_dist, resolve_dist_fn,
                              staged_m)
 from repro.core.graph import PaddedCSR
 from repro.core.metrics import SearchStats
+from repro.kernels.registry import with_kernel_tables
+
+
+def shard_map(f, mesh, in_specs, out_specs):
+    """``jax.shard_map`` with replication checking off (stats leaves are
+    reduced to uniform values manually)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
+
+
+def make_search_mesh(shape, names=("data", "model")) -> Mesh:
+    """A device mesh for the search paths (explicit Auto axis types)."""
+    names = tuple(names)
+    return jax.make_mesh(tuple(shape), names,
+                         axis_types=(jax.sharding.AxisType.Auto,)
+                         * len(names))
 
 
 # ---------------------------------------------------------------------------
@@ -145,9 +128,9 @@ def walker_sharded_search(
     n_walkers = int(mesh.shape[walker_axis])
     n_top, n_nodes = graph.n_top, graph.n_nodes
 
-    def per_query(nbrs, vectors, medoid, flat, q, walker):
+    def per_query(nbrs, vectors, medoid, flat, tiles, q, walker):
         g = PaddedCSR(nbrs=nbrs, vectors=vectors, medoid=medoid,
-                      n_top=n_top, flat=flat)
+                      n_top=n_top, flat=flat, vector_tiles=tiles)
         cap = cfg.queue_len
         frontier = fq.make_frontier(cap)
         visited = vs.make_visited(cfg.visited_mode, n_nodes, cfg.hash_bits)
@@ -212,21 +195,22 @@ def walker_sharded_search(
         ids, dists = fq.results(frontier, cfg.k)
         return ids, dists, stats
 
-    def shard_body(nbrs, vectors, medoid, flat, q_local):
+    def shard_body(nbrs, vectors, medoid, flat, tiles, q_local):
         walker = jax.lax.axis_index(walker_axis).astype(jnp.int32)
         fn = functools.partial(per_query, nbrs, vectors, medoid, flat,
-                               walker=walker)
+                               tiles, walker=walker)
         ids, dists, stats = jax.vmap(fn)(q_local)
         return ids, dists, stats
 
     rep = P()   # graph replicated on all devices
     fn = shard_map(
         shard_body, mesh=mesh,
-        in_specs=(rep, rep, rep, rep, P(data_axis, None)),
+        in_specs=(rep, rep, rep, rep, rep, P(data_axis, None)),
         out_specs=(P(data_axis, None), P(data_axis, None),
                    jax.tree.map(lambda _: P(data_axis), SearchStats.zero())),
     )
-    return fn(graph.nbrs, graph.vectors, graph.medoid, graph.flat, queries)
+    return fn(graph.nbrs, graph.vectors, graph.medoid, graph.flat,
+              graph.vector_tiles, queries)
 
 
 # ---------------------------------------------------------------------------
@@ -239,6 +223,9 @@ class ShardedIndex(NamedTuple):
     vectors: jax.Array     # (S, N_s, d)
     medoids: jax.Array     # (S,)
     offsets: jax.Array     # (S,) global id = offsets[s] + local id
+    # the Pallas kernels' row-tiled copy (see kernels.registry.
+    # with_kernel_tables); None for the ref backends
+    vector_tiles: Optional[jax.Array] = None   # (S, N_s, k, 128)
 
     @property
     def num_shards(self) -> int:
@@ -250,11 +237,17 @@ class ShardedIndex(NamedTuple):
 
 
 def build_partitioned(data: np.ndarray, num_shards: int, degree: int = 24,
+                      mesh: Optional[Mesh] = None, shard_axis: str = "model",
                       **nsg_kw) -> ShardedIndex:
     """Partition the corpus contiguously and build one sub-index per shard.
 
     (Real deployments partition by clustering; contiguous split keeps the
     builder simple and the search path identical.)
+
+    With ``mesh``, every stacked array is placed once, here, with shard s
+    on the devices at position s of ``mesh``'s ``shard_axis`` — the
+    placement :func:`corpus_sharded_search` reads, so no call moves the
+    corpus.  Without it the arrays stay on the default device.
     """
     from repro.core.build import build_nsg
     n = data.shape[0]
@@ -282,11 +275,14 @@ def build_partitioned(data: np.ndarray, num_shards: int, degree: int = 24,
                  np.full((pad, r), max_n, np.int32)]).astype(np.int32)
         else:
             nbrs[s] = nbrs[s].astype(np.int32)
-    return ShardedIndex(
-        nbrs=jnp.asarray(np.stack(nbrs)),
-        vectors=jnp.asarray(np.stack(vecs)),
-        medoids=jnp.asarray(np.asarray(meds, np.int32)),
-        offsets=jnp.asarray(np.asarray(offs, np.int32)))
+    arrays = dict(nbrs=np.stack(nbrs), vectors=np.stack(vecs),
+                  medoids=np.asarray(meds, np.int32),
+                  offsets=np.asarray(offs, np.int32))
+    if mesh is None:
+        return ShardedIndex(**{k: jnp.asarray(v) for k, v in arrays.items()})
+    on_shards = NamedSharding(mesh, P(shard_axis))
+    return ShardedIndex(**{k: jax.device_put(v, on_shards)
+                           for k, v in arrays.items()})
 
 
 def corpus_sharded_search(
@@ -307,14 +303,15 @@ def corpus_sharded_search(
     dist_fn = resolve_dist_fn(cfg, dist_fn)
     n_top = 0
 
-    def shard_body(nbrs, vectors, medoid, offset, q_local):
+    def shard_body(nbrs, vectors, medoid, offset, tiles, q_local):
         nbrs = nbrs[0]
         vectors = vectors[0]
         medoid = medoid[0]
         offset = offset[0]
         g = PaddedCSR(nbrs=nbrs, vectors=vectors, medoid=medoid, n_top=n_top,
                       flat=jnp.zeros((0, nbrs.shape[1], vectors.shape[1]),
-                                     vectors.dtype))
+                                     vectors.dtype),
+                      vector_tiles=None if tiles is None else tiles[0])
         # batch-major engine inside the shard: the device's whole local
         # query batch advances through one while_loop / one distance launch
         # per step (bit-identical to the per-query vmap it replaces)
@@ -333,11 +330,11 @@ def corpus_sharded_search(
     fn = shard_map(
         shard_body, mesh=mesh,
         in_specs=(P(shard_axis), P(shard_axis), P(shard_axis), P(shard_axis),
-                  P(data_axis, None)),
+                  P(shard_axis), P(data_axis, None)),
         out_specs=(P(data_axis, None), P(data_axis, None)),
     )
     return fn(index.nbrs, index.vectors, index.medoids, index.offsets,
-              queries)
+              index.vector_tiles, queries)
 
 
 # ---------------------------------------------------------------------------
@@ -364,7 +361,8 @@ def walker_engine_search(index, queries, params, mesh: Optional[Mesh] = None):
                         mesh=mesh)
 
 
-def build_partitioned_index(data, num_shards: int, spec=None) -> ShardedIndex:
+def build_partitioned_index(data, num_shards: int, spec=None,
+                            mesh: Optional[Mesh] = None) -> ShardedIndex:
     """Corpus partitioning driven by an :class:`repro.ann.IndexSpec`.
 
     Honors the spec's builder knobs (degree, knn_k, ef_construction, passes,
@@ -373,7 +371,9 @@ def build_partitioned_index(data, num_shards: int, spec=None) -> ShardedIndex:
     its metric: for ``cosine`` the corpus is unit-normalized before
     partitioning (cosine == ip on the unit sphere), matching
     ``AnnIndex.build``.  Returns a :class:`ShardedIndex` for
-    :func:`corpus_sharded_search` / :func:`corpus_engine_searcher`.
+    :func:`corpus_sharded_search` / :func:`corpus_engine_searcher`; with
+    ``mesh`` its shards are placed on the mesh's ``model`` axis (see
+    :func:`build_partitioned`).
     """
     from repro.ann.spec import IndexSpec
     from repro.core.build import normalize_rows
@@ -390,7 +390,8 @@ def build_partitioned_index(data, num_shards: int, spec=None) -> ShardedIndex:
         data, num_shards, degree=spec.degree, knn_k=spec.resolved_knn_k,
         alpha=spec.alpha, ef_construction=spec.resolved_ef,
         passes=spec.passes, seed=spec.seed, metric=build_metric,
-        build_batch=spec.build_batch, build_backend=spec.build_backend)
+        build_batch=spec.build_batch, build_backend=spec.build_backend,
+        mesh=mesh)
 
 
 def corpus_engine_searcher(index: ShardedIndex, params, mesh: Mesh,
@@ -409,11 +410,10 @@ def corpus_engine_searcher(index: ShardedIndex, params, mesh: Mesh,
     cfg = params.to_search_config(metric).with_(m_max=1, staged=False,
                                                 num_walkers=1)
     normalize = metric == "cosine"
+    index = with_kernel_tables(index, cfg.dist_backend)
 
     @jax.jit
-    def jitted(nbrs, vectors, medoids, offsets, q):
-        idx = ShardedIndex(nbrs=nbrs, vectors=vectors, medoids=medoids,
-                           offsets=offsets)
+    def jitted(idx, q):
         q = q.astype(jnp.float32)
         if normalize:
             q = q / jnp.maximum(
@@ -427,7 +427,6 @@ def corpus_engine_searcher(index: ShardedIndex, params, mesh: Mesh,
         q = jnp.asarray(queries)
         if q.ndim != 2:
             raise ValueError(f"queries must be (B, d), got {q.shape}")
-        return jitted(index.nbrs, index.vectors, index.medoids,
-                      index.offsets, q)
+        return jitted(index, q)
 
     return fn

@@ -37,6 +37,14 @@ class PaddedCSR(NamedTuple):
     # f32 ``vectors`` stay the seeding + exact-re-ranking table.
     codes: Optional[jax.Array] = None    # (N, d) int8 | bfloat16
     scales: Optional[jax.Array] = None   # (N, 1) per-vector | (1, d) per-dim
+    # the Pallas gather kernels' copies of ``vectors`` and of int8
+    # ``codes`` in the row-tiled layout they read (kernels.platform.
+    # row_tiles: (N, ceil(d/128), 128), zero-padded).  None until a
+    # searcher with a Pallas backend is made (kernels.registry.
+    # with_kernel_tables), which lays them out once, outside any jitted
+    # call: for d > 128 the relayout is a copy of the whole table.
+    vector_tiles: Optional[jax.Array] = None   # (N, k, 128) f32
+    code_tiles: Optional[jax.Array] = None     # (N, k, 128) int8
 
     @property
     def n_nodes(self) -> int:

@@ -26,6 +26,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.platform import pallas_call
+
 
 def _xor_perm(x: jax.Array, j: int) -> jax.Array:
     """x[i ^ j] for power-of-two j, as reshape + flip (no gather)."""
@@ -73,7 +75,7 @@ def _sort_kernel(k_ref, p0_ref, p1_ref, ko_ref, p0o_ref, p1o_ref, *, n: int):
 
 
 def sort_pairs(
-    keys: jax.Array, p0: jax.Array, p1: jax.Array, *, interpret: bool = True,
+    keys: jax.Array, p0: jax.Array, p1: jax.Array,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Row-wise ascending co-sort by (key, p0).  Shapes (B, n), n = 2**k.
 
@@ -83,7 +85,7 @@ def sort_pairs(
     assert n & (n - 1) == 0, f"bitonic length {n} must be a power of two"
     kernel = functools.partial(_sort_kernel, n=n)
     specs = [pl.BlockSpec((1, n), lambda b: (b, 0)) for _ in range(3)]
-    return pl.pallas_call(
+    return pallas_call(
         kernel,
         grid=(bsz,),
         in_specs=specs,
@@ -93,5 +95,4 @@ def sort_pairs(
             jax.ShapeDtypeStruct((bsz, n), jnp.int32),
             jax.ShapeDtypeStruct((bsz, n), jnp.int32),
         ),
-        interpret=interpret,
     )(keys.astype(jnp.float32), p0.astype(jnp.int32), p1.astype(jnp.int32))

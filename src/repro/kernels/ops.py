@@ -1,8 +1,8 @@
 """jit'd public wrappers around the Pallas kernels + search integration.
 
-``interpret`` defaults to True because this container is CPU-only; on real
-TPU hardware set ``repro.kernels.ops.INTERPRET = False`` (or pass
-``interpret=False``) and the same code lowers through Mosaic.
+Every kernel lowers through Mosaic when compiled for a TPU and runs in
+Pallas interpret mode when compiled for the CPU
+(:func:`repro.kernels.platform.pallas_call`); there is nothing to set.
 """
 from __future__ import annotations
 
@@ -14,19 +14,16 @@ import jax.numpy as jnp
 
 from repro.kernels import bitonic as _bitonic
 from repro.kernels import l2dist as _l2
-from repro.kernels import ref as _ref
-
-INTERPRET = True   # flip on real TPU
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("impl", "interpret", "g", "metric"))
+@functools.partial(jax.jit, static_argnames=("impl", "g", "metric"))
 def l2dist(
-    table: jax.Array, ids: jax.Array, queries: jax.Array,
-    impl: str = "rowgather", interpret: bool | None = None, g: int = 8,
-    metric: str = "l2",
+    tiles: jax.Array, ids: jax.Array, queries: jax.Array,
+    impl: str = "rowgather", g: int = 8, metric: str = "l2",
 ) -> jax.Array:
-    """Fused gather + distance: (N,d), (B,C), (B,d) -> (B,C) f32.
+    """Fused gather + distance: (N, k, 128) row-tiled table
+    (:func:`repro.kernels.platform.row_tiles`), (B, C) ids, (B, d) queries
+    -> (B, C) f32.
 
     This is the batch-major hot-path launch: the traversal engine calls it
     ONCE per global step with the whole query batch's flattened candidate
@@ -39,31 +36,24 @@ def l2dist(
     ``g`` is the DMA tile size ("dma" impl only; requires C % g == 0 —
     ``registry.pad_ids_to_tile`` handles ragged candidate counts).
     """
-    itp = INTERPRET if interpret is None else interpret
     kmetric = "ip" if metric in ("ip", "cosine") else "l2"
-    if impl == "ref":
-        return _ref.dist_ref(table, ids, queries, metric=kmetric)
     if impl == "rowgather":
-        return _l2.l2dist_rowgather(table, ids, queries, interpret=itp,
-                                    metric=kmetric)
+        return _l2.l2dist_rowgather(tiles, ids, queries, metric=kmetric)
     if impl == "dma":
-        return _l2.l2dist_dma(table, ids, queries, g=g, interpret=itp,
-                              metric=kmetric)
+        return _l2.l2dist_dma(tiles, ids, queries, g=g, metric=kmetric)
     raise ValueError(impl)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def sort_pairs(keys, p0, p1, interpret: bool | None = None):
+@jax.jit
+def sort_pairs(keys, p0, p1):
     """Row-wise (B, n) ascending co-sort by (key, p0); n must be 2**k."""
-    itp = INTERPRET if interpret is None else interpret
-    return _bitonic.sort_pairs(keys, p0, p1, interpret=itp)
+    return _bitonic.sort_pairs(keys, p0, p1)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@jax.jit
 def topl_merge(
     q_dists: jax.Array, q_ids: jax.Array, q_meta: jax.Array,
     c_dists: jax.Array, c_ids: jax.Array,
-    interpret: bool | None = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Frontier merge on the bitonic kernel (B-batched, mirrors queue.insert).
 
@@ -100,7 +90,7 @@ def topl_merge(
     p0 = ((ids & 0xFF) << 1) | (packed & 1)
     positions = jnp.broadcast_to(
         jnp.arange(n, dtype=jnp.int32)[None, :], (bsz, n))
-    _, _, pos = sort_pairs(key_hi, p0, positions, interpret=interpret)
+    _, _, pos = sort_pairs(key_hi, p0, positions)
     # gather full rows by the returned original positions
     take = jax.vmap(lambda a, p: a[p])
     ids_g = take(ids, pos)
@@ -113,7 +103,7 @@ def topl_merge(
     dists_g = jnp.where(dup, big, dists_g)
 
     # pass 2: by (dist, id)
-    d2, i2, pk2 = sort_pairs(dists_g, ids_g, packed_g, interpret=interpret)
+    d2, i2, pk2 = sort_pairs(dists_g, ids_g, packed_g)
     rank = jnp.arange(n, dtype=jnp.int32)[None, :]
     surv = (pk2 & 1 == 1) & (i2 != invalid) & (rank < qlen)
     up = jnp.min(jnp.where(surv, rank, qlen), axis=1).astype(jnp.int32)

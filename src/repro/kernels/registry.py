@@ -17,14 +17,15 @@ Built-in backends:
 * ``rowgather`` — scalar-prefetch Pallas kernel: candidate ids drive the
   BlockSpec index_map so the pipeline streams exactly the needed rows; the
   batch rides in the kernel grid's leading dimension.
-* ``dma``       — explicit-DMA tile gather + MXU reduction; candidate counts
+* ``dma``       — explicit-DMA tile gather (G rows per grid step); candidate counts
   are padded to the ``cfg.dma_group`` tile (padding ids map to +inf and are
   sliced off, so ragged M·R shapes are transparent to callers).
 * ``dedup_gather`` — batch-deduplicating gather (``kernels.dedup``): the
-  step's flattened (B·C,) candidate ids sort/unique first and each DISTINCT
-  row is gathered ONCE for the whole batch, reduced against the stacked
-  query block, and scattered back to lanes.  Bit-identical to ``ref``; the
-  saved gathers are exactly ``SearchStats.batch_dup_comps``.
+  step's flattened (B·C,) candidate ids are sorted first and each DISTINCT
+  row is gathered ONCE for the whole batch while every lane's pair is
+  reduced.  Bit-identical to ``rowgather``; the saved gathers are exactly
+  ``SearchStats.batch_dup_comps`` when the step fits one launch
+  (``kernels.platform.MAX_PAIRS``).
 
 Quantized backends (``ref_int8`` | ``rowgather_int8`` | ``dedup_gather_int8``
 | ``ref_bf16``, from ``repro.quant.kernels`` and ``kernels.dedup``) gather
@@ -38,25 +39,64 @@ New kernels register with :func:`register_backend` and become selectable via
 """
 from __future__ import annotations
 
-from typing import Callable, Dict, Tuple
+from typing import Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops
+from repro.kernels.platform import row_tiles
 
 # factory(cfg: SearchConfig) -> DistFn (see core.bfis.DistFn)
 DistFactory = Callable[..., Callable]
 
 _REGISTRY: Dict[str, DistFactory] = {}
+# backend name -> the row-tiled table its Pallas kernel reads
+_TILED: Dict[str, str] = {}
 
 
-def register_backend(name: str):
-    """Decorator: register ``factory(cfg) -> DistFn`` under ``name``."""
+def register_backend(name: str, tiles: Optional[str] = None):
+    """Decorator: register ``factory(cfg) -> DistFn`` under ``name``.
+    ``tiles`` names the row-tiled table the backend's kernel reads
+    (``"vector_tiles"`` | ``"code_tiles"``, see :func:`with_kernel_tables`).
+    """
     def deco(factory: DistFactory) -> DistFactory:
         _REGISTRY[name] = factory
+        if tiles is not None:
+            _TILED[name] = tiles
         return factory
     return deco
+
+
+def with_kernel_tables(graph, backend: str):
+    """``graph`` (a ``PaddedCSR`` or ``ShardedIndex``) plus the row-tiled
+    table ``backend``'s Pallas kernel gathers from: ``vector_tiles`` from
+    the f32 vectors, ``code_tiles`` from int8 codes
+    (:func:`repro.kernels.platform.row_tiles`).  Laid out here, once,
+    outside any jitted search — for d > 128 the layout is a copy of the
+    whole table — and only for the backends that read it.  Unchanged for
+    the ``ref`` backends and when the table is already there."""
+    field = _TILED.get(backend)
+    if field is None or getattr(graph, field, None) is not None:
+        return graph
+    src = getattr(graph, "vectors" if field == "vector_tiles" else "codes",
+                  None)
+    if src is None or (field == "code_tiles" and src.dtype != jnp.int8):
+        return graph      # the backend's own check says what is missing
+    return graph._replace(**{field: row_tiles(src)})
+
+
+def kernel_table(graph, field: str) -> jax.Array:
+    """``graph``'s row-tiled ``field``; raises when the graph was not
+    given it by :func:`with_kernel_tables`."""
+    table = getattr(graph, field, None)
+    if table is None:
+        raise ValueError(
+            f"the graph carries no {field}: the Pallas backends read a "
+            f"row-tiled table that kernels.registry.with_kernel_tables("
+            f"graph, backend) lays out once, outside the jitted search "
+            f"(AnnIndex searchers and AnnEngine do this themselves)")
+    return table
 
 
 def available_backends() -> Tuple[str, ...]:
@@ -89,8 +129,7 @@ def pad_ids_to_tile(ids: jax.Array, tile: int, n_nodes: int) -> jax.Array:
 
 
 def make_dist_fn(impl: str = "rowgather", *, metric: str = "l2",
-                 dma_group: int = 8,
-                 interpret: bool | None = None) -> Callable:
+                 dma_group: int = 8) -> Callable:
     """Adapter producing a batch-major ``core.bfis.DistFn`` that routes the
     whole batch's (B, M, R) expansion through ONE (B, C) kernel launch
     (C = M·R, padded to the DMA tile for ``impl="dma"``).
@@ -98,9 +137,8 @@ def make_dist_fn(impl: str = "rowgather", *, metric: str = "l2",
     ``metric`` is the index metric tag ("l2" | "ip" | "cosine"); every
     backend serves every metric (cosine = ip on pre-normalized vectors).
 
-    Note: the kernel reads the flat embedding table; the two-level flattened
-    layout is exploited by the pipeline's row streaming itself (hot rows stay
-    in VMEM across adjacent grid steps), so no separate path is needed.
+    The kernels read ``graph.vector_tiles``, the row-tiled copy of the
+    table laid out by :func:`with_kernel_tables`.
     """
     if impl == "ref":
         from repro.core.bfis import make_ref_dist_fn
@@ -111,9 +149,8 @@ def make_dist_fn(impl: str = "rowgather", *, metric: str = "l2",
         flat = nbr_ids.reshape(b, m * r)
         if impl == "dma":
             flat = pad_ids_to_tile(flat, dma_group, graph.n_nodes)
-        d = ops.l2dist(graph.vectors, flat, queries,
-                       impl=impl, interpret=interpret, g=dma_group,
-                       metric=metric)
+        d = ops.l2dist(kernel_table(graph, "vector_tiles"), flat, queries,
+                       impl=impl, g=dma_group, metric=metric)
         return d[:, :m * r].reshape(b, m, r)
     return dist_fn
 
@@ -129,12 +166,12 @@ def _ref_backend(cfg):
     return make_ref_dist_fn(_cfg_metric(cfg))
 
 
-@register_backend("rowgather")
+@register_backend("rowgather", tiles="vector_tiles")
 def _rowgather_backend(cfg):
     return make_dist_fn("rowgather", metric=_cfg_metric(cfg))
 
 
-@register_backend("dma")
+@register_backend("dma", tiles="vector_tiles")
 def _dma_backend(cfg):
     return make_dist_fn("dma", metric=_cfg_metric(cfg),
                         dma_group=int(getattr(cfg, "dma_group", 8)))

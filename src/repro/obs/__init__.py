@@ -22,7 +22,7 @@ from __future__ import annotations
 from typing import Optional
 
 from .histogram import LogHistogram
-from .jaxbridge import device_annotation, have_profiler
+from .jaxbridge import device_annotation
 from .registry import Counter, Gauge, Histogram, MetricsRegistry
 from .trace import NULL_TRACER, SpanHandle, TraceRecorder
 
@@ -30,7 +30,7 @@ __all__ = [
     "LogHistogram",
     "MetricsRegistry", "Counter", "Gauge", "Histogram",
     "TraceRecorder", "SpanHandle", "NULL_TRACER",
-    "device_annotation", "have_profiler",
+    "device_annotation",
     "Observability", "NULL_OBS",
 ]
 

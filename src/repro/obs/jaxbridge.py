@@ -12,44 +12,19 @@ path never touches the profiler machinery by default:
 
     with device_annotation("ann_dispatch/bucket8", enabled=obs.profile):
         out = compiled(queries)
-
-The import of ``jax.profiler`` is lazy and failure-tolerant — on a build
-without profiler support the annotation degrades to the null context
-instead of raising.
 """
 from __future__ import annotations
 
 from contextlib import nullcontext
 
-__all__ = ["device_annotation", "have_profiler"]
+from jax.profiler import TraceAnnotation
 
-_TraceAnnotation = None
-_probed = False
-
-
-def _resolve():
-    global _TraceAnnotation, _probed
-    if not _probed:
-        _probed = True
-        try:
-            from jax.profiler import TraceAnnotation
-            _TraceAnnotation = TraceAnnotation
-        except Exception:
-            _TraceAnnotation = None
-    return _TraceAnnotation
-
-
-def have_profiler() -> bool:
-    """True if ``jax.profiler.TraceAnnotation`` is importable."""
-    return _resolve() is not None
+__all__ = ["device_annotation"]
 
 
 def device_annotation(name: str, enabled: bool = False):
     """Context manager: ``jax.profiler.TraceAnnotation(name)`` when
-    ``enabled`` and the profiler is available, else a no-op."""
+    ``enabled``, else a no-op."""
     if not enabled:
         return nullcontext()
-    cls = _resolve()
-    if cls is None:
-        return nullcontext()
-    return cls(name)
+    return TraceAnnotation(name)

@@ -15,9 +15,10 @@ analysis says bounds expansion throughput.  They register themselves with
   per-dimension scales dequantize the gathered rows and reduce in f32
   (memory win only).
 * ``rowgather_int8`` — scalar-prefetch Pallas kernel: candidate ids drive
-  the BlockSpec index_map of BOTH the int8 code rows and their per-vector
-  scale rows, the VPU accumulates the code dot in int32 and rescales once.
-  Per-vector scales only (the integer path is the point of the kernel).
+  the BlockSpec index_map of the row-tiled int8 codes
+  (``PaddedCSR.code_tiles``, see ``registry.with_kernel_tables``), the VPU accumulates the code dot in int32
+  and rescales once by the candidate's per-vector scale.  Per-vector
+  scales only (the integer path is the point of the kernel).
 * ``ref_bf16``       — pure-jnp bf16 gather, f32 reduction (scale-free).
 
 Every backend serves every metric: "l2" uses
@@ -36,14 +37,18 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.registry import register_backend
+from repro.kernels.platform import pallas_call, row_tiles, split_batch
+from repro.kernels.registry import kernel_table, register_backend
 from repro.quant.codec import quantize_query
 
 
-def require_codes(graph, dtype: str):
+def require_codes(graph, dtype: str, tiled: bool = False):
     """Trace-time check that the graph carries a ``dtype`` quantized table.
 
-    Raises with build guidance instead of a shape error deep inside jit."""
+    Raises with build guidance instead of a shape error deep inside jit.
+    Returns (codes, scales); with ``tiled`` (the int8 Pallas kernels) the
+    row-tiled ``code_tiles`` in place of the codes, and per-vector scales
+    are required."""
     codes, scales = getattr(graph, "codes", None), getattr(graph, "scales",
                                                            None)
     if codes is None or codes.size == 0:
@@ -55,7 +60,13 @@ def require_codes(graph, dtype: str):
         raise ValueError(
             f"index is quantized as {codes.dtype}, not {dtype}; pick the "
             f"matching backend or rebuild with IndexSpec(quant=\"{dtype}\")")
-    return codes, scales
+    if not tiled:
+        return codes, scales
+    if scales.shape[0] == 1:
+        raise NotImplementedError(
+            "the int8 Pallas kernels implement the per-vector-scale integer "
+            "path; per-dimension scales are served by 'ref_int8'")
+    return kernel_table(graph, "code_tiles"), scales
 
 
 def _kmetric(metric: str) -> str:
@@ -138,89 +149,94 @@ def make_bf16_dist_fn(metric: str = "l2"):
 # rowgather_int8: scalar-prefetch Pallas kernel (int32 accumulate + rescale)
 # ---------------------------------------------------------------------------
 
+def int8_query_operands(queries: jax.Array, k: int):
+    """Quantize a (B, d) f32 query batch once per call: (B, k, 128) int32
+    query codes (zero-padded like the code tiles) and (B, 2) f32
+    ``[query scale, ‖q‖²]``."""
+    qc, qs = quantize_query(queries)                       # (B,d) i32, (B,1)
+    q2 = jnp.sum(queries.astype(jnp.float32) ** 2, axis=-1, keepdims=True)
+    qc = row_tiles(qc)
+    assert qc.shape[1] == k, (qc.shape, k)
+    return qc, jnp.concatenate([qs, q2], axis=1)
+
+
+def int8_pair_dist(row: jax.Array, qc: jax.Array, s, qs, q2,
+                   metric: str) -> jax.Array:
+    """One (code row, query) distance: int32-accumulated integer dot, ONE
+    f32 rescale — the op order of ``ref_int8``'s per-vector path, so every
+    int8 kernel is bit-identical to it."""
+    row = row.astype(jnp.int32)                            # int8 -> i32
+    acc = jnp.sum(row * qc)                                # i32 accumulation
+    xq = s * qs * acc.astype(jnp.float32)                  # one f32 rescale
+    if metric == "ip":
+        return -xq
+    rn2 = jnp.sum(row * row)                               # i32 accumulation
+    return jnp.maximum(s * s * rn2.astype(jnp.float32) - 2.0 * xq + q2, 0.0)
+
+
 def _rowgather_int8_kernel(ids_ref, row_ref, scale_ref, qc_ref, qmeta_ref,
                            out_ref, *, n_nodes: int, metric: str):
     b = pl.program_id(0)
     c = pl.program_id(1)
-    sid = ids_ref[b, c]
-    row = row_ref[0, :].astype(jnp.int32)                  # int8 -> i32
-    qc = qc_ref[0, :]                                      # i32 query codes
-    acc = jnp.sum(row * qc)                                # i32 accumulation
-    s = scale_ref[0, 0]                                    # per-vector scale
-    xq = s * qmeta_ref[0, 0] * acc.astype(jnp.float32)     # one f32 rescale
-    if metric == "ip":
-        dist = -xq
-    else:
-        rn2 = jnp.sum(row * row)                           # i32 accumulation
-        dist = jnp.maximum(
-            s * s * rn2.astype(jnp.float32) - 2.0 * xq + qmeta_ref[0, 1],
-            0.0)
-    out_ref[0, 0] = jnp.where(sid < n_nodes, dist, jnp.float32(jnp.inf))
+    dist = int8_pair_dist(row_ref[...], qc_ref[b], scale_ref[b, c],
+                          qmeta_ref[b, 0], qmeta_ref[b, 1], metric)
+    out_ref[b, c] = jnp.where(ids_ref[b, c] < n_nodes, dist,
+                              jnp.float32(jnp.inf))
 
 
+@split_batch
 def int8dist_rowgather(
-    codes: jax.Array, scales: jax.Array, ids: jax.Array, queries: jax.Array,
-    *, interpret: bool | None = None, metric: str = "l2",
+    code_tiles: jax.Array, scales: jax.Array, ids: jax.Array,
+    queries: jax.Array, *, metric: str = "l2",
 ) -> jax.Array:
-    """(N,d) int8 codes + (N,1) scales, (B,C) ids, (B,d) f32 queries ->
-    (B,C) f32 distances.
+    """(N, k, 128) row-tiled int8 codes + (N, 1) scales, (B, C) ids, (B, d)
+    f32 queries -> (B, C) f32 distances.
 
-    The prefetched candidate ids drive TWO index_maps — the int8 code row
-    and its (1, 1) scale row stream together, so the pipeline's gather-side
-    traffic is ~d bytes per candidate instead of 4d.  Query quantization
-    (codes + [scale, ‖q‖²] meta) happens once per call outside the grid.
+    The prefetched candidate ids drive the code table's index_map, so the
+    pipeline's gather-side traffic is ~d bytes per candidate instead of
+    4d.  Each candidate's scale (4 bytes) is gathered outside the kernel
+    and read from SMEM; query quantization (codes + [scale, ‖q‖²] meta)
+    happens once per call outside the grid.
     """
-    from repro.kernels import ops
-    itp = ops.INTERPRET if interpret is None else interpret
-    n, d = codes.shape
+    n, k, lanes = code_tiles.shape
     bsz, c = ids.shape
     if scales.shape != (n, 1):
-        # the scale BlockSpec below streams scales BY CANDIDATE ROW ID —
-        # per-dimension (1, d) scales would silently mis-read block (0, 0)
         raise ValueError(
             f"int8dist_rowgather needs per-vector scales of shape "
             f"({n}, 1), got {scales.shape}; per-dimension scales are "
             f"served by the 'ref_int8' backend")
-    qc, qs = quantize_query(queries)                       # (B,d) i8, (B,1)
-    q2 = jnp.sum(queries.astype(jnp.float32) ** 2, axis=-1, keepdims=True)
-    qmeta = jnp.concatenate([qs, q2], axis=1)              # (B, 2) f32
+    qc, qmeta = int8_query_operands(queries, k)
+    s = scales[jnp.minimum(ids, n - 1), 0]                 # (B, C) f32
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(bsz, c),
         in_specs=[
             pl.BlockSpec(
-                (1, d), lambda b, cc, ids_ref: (jnp.minimum(
-                    ids_ref[b, cc], n - 1), 0)),
-            pl.BlockSpec(
-                (1, 1), lambda b, cc, ids_ref: (jnp.minimum(
-                    ids_ref[b, cc], n - 1), 0)),
-            pl.BlockSpec((1, d), lambda b, cc, ids_ref: (b, 0)),
-            pl.BlockSpec((1, 2), lambda b, cc, ids_ref: (b, 0)),
+                (None, k, lanes), lambda b, cc, ids_ref: (jnp.minimum(
+                    ids_ref[b, cc], n - 1), 0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((bsz, k, lanes), lambda b, cc, ids_ref: (0, 0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
-        out_specs=pl.BlockSpec((1, 1), lambda b, cc, ids_ref: (b, cc)),
+        out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
     )
     kernel = functools.partial(_rowgather_int8_kernel, n_nodes=n,
                                metric=_kmetric(metric))
-    return pl.pallas_call(
+    return pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bsz, c), jnp.float32),
-        interpret=itp,
-    )(ids, codes, scales, qc, qmeta)
+    )(ids, code_tiles, s, qc, qmeta)
 
 
 def make_rowgather_int8_dist_fn(metric: str = "l2"):
     """Batch-major Pallas int8 DistFn (mirroring ``registry.make_dist_fn``):
     the whole (B, M·R) candidate grid is ONE scalar-prefetch launch."""
     def dist_fn(graph, active_ids, nbr_ids, queries):
-        codes, scales = require_codes(graph, "int8")
-        if scales.shape[0] == 1:
-            raise NotImplementedError(
-                "rowgather_int8 implements the per-vector-scale integer "
-                "path; per-dimension scales are served by 'ref_int8'")
+        code_tiles, scales = require_codes(graph, "int8", tiled=True)
         b, m, r = nbr_ids.shape
-        d = int8dist_rowgather(codes, scales,
+        d = int8dist_rowgather(code_tiles, scales,
                                nbr_ids.reshape(b, m * r), queries,
                                metric=metric)
         return d.reshape(b, m, r)
@@ -240,7 +256,7 @@ def _ref_int8_backend(cfg):
     return make_int8_dist_fn(_cfg_metric(cfg))
 
 
-@register_backend("rowgather_int8")
+@register_backend("rowgather_int8", tiles="code_tiles")
 def _rowgather_int8_backend(cfg):
     return make_rowgather_int8_dist_fn(_cfg_metric(cfg))
 

@@ -69,6 +69,7 @@ from repro.core.bfis import (DistFn, bfis_search_batch, hnsw_search_batch,
 from repro.core.distributed import ShardedIndex, corpus_engine_searcher
 from repro.core.metrics import SearchStats, recall_at_k, telemetry_per_lane
 from repro.core.speedann import search_speedann_batch
+from repro.kernels.registry import with_kernel_tables
 from repro.obs import NULL_OBS, LogHistogram, Observability, device_annotation
 
 DEFAULT_BUCKETS = (1, 2, 4, 8, 16, 32, 64)
@@ -205,6 +206,7 @@ class AnnEngine:
             # legacy pipeline only — the facade path serves through
             # index.searcher and never touches these
             self._dist_fn = resolve_dist_fn(cfg, dist_fn)
+            self.graph = with_kernel_tables(graph, cfg.dist_backend)
             self._search = _ALGORITHMS[algorithm]
             if (algorithm == "bfis" and self.index is not None
                     and self.index.hnsw is not None):
@@ -300,11 +302,12 @@ class AnnEngine:
             n_nodes = self.graph.n_nodes
 
             @jax.jit
-            def jitted(nbrs, vectors, medoid, flat, codes, scales, ofn_arr,
-                       q):
+            def jitted(nbrs, vectors, medoid, flat, codes, scales,
+                       vector_tiles, code_tiles, ofn_arr, q):
                 g = graph_cls(nbrs=nbrs, vectors=vectors, medoid=medoid,
                               n_top=n_top, flat=flat, codes=codes,
-                              scales=scales)
+                              scales=scales, vector_tiles=vector_tiles,
+                              code_tiles=code_tiles)
                 q = q.astype(jnp.float32)
                 if normalize:
                     q = normalize_queries(q)
@@ -316,7 +319,8 @@ class AnnEngine:
             def fn(q, _j=jitted):
                 gr = self.graph
                 return _j(gr.nbrs, gr.vectors, gr.medoid, gr.flat,
-                          gr.codes, gr.scales, self._ofn, q)
+                          gr.codes, gr.scales, gr.vector_tiles,
+                          gr.code_tiles, self._ofn, q)
             self._jit_cache[bucket] = fn
         else:
             self.cache_hits += 1

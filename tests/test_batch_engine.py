@@ -23,13 +23,14 @@ import numpy as np
 import pytest
 
 from repro.ann import AnnIndex, IndexSpec, SearchParams
+from repro.ann.index import quantize_graph
 from repro.core import build_nsg, recall_at_k
 from repro.core.bfis import search_topm, search_topm_batch
 from repro.core.config import SearchConfig
 from repro.core.metrics import SearchStats, batch_unique_counts
 from repro.core.speedann import search_speedann, search_speedann_batch
 from repro.data import make_vector_dataset
-from repro.quant.codec import fit_scales, quantize
+from repro.kernels.registry import with_kernel_tables
 from repro.quant.scheme import QuantSpec
 
 K = 10
@@ -43,16 +44,15 @@ def ds():
 
 @pytest.fixture(scope="module")
 def graph(ds):
-    return build_nsg(ds.base, degree=12, knn_k=12, ef_construction=24,
-                     passes=1)
+    # with the row-tiled table the Pallas backends read
+    return with_kernel_tables(
+        build_nsg(ds.base, degree=12, knn_k=12, ef_construction=24,
+                  passes=1), "rowgather")
 
 
 def quantized(graph, dtype):
-    spec = QuantSpec(dtype=dtype)
-    scales = fit_scales(graph.vectors, spec)
-    return graph._replace(
-        codes=quantize(graph.vectors, spec, scales),
-        scales=jnp.asarray(scales, jnp.float32))
+    return with_kernel_tables(quantize_graph(graph, QuantSpec(dtype=dtype)),
+                              "rowgather_int8")
 
 
 BASE = SearchConfig(k=K, queue_len=32, m_max=3, staged=False, max_steps=96)

@@ -14,7 +14,8 @@ import pytest
 
 from repro.ann import AnnIndex, IndexSpec, SearchParams
 from repro.core.build import (_upper_level_ids, build_nsg, build_nsg_serial,
-                              exact_knn, knn_graph)
+                              connect_from, exact_knn, knn_graph, reachable)
+from repro.data import make_vector_dataset
 from repro.core.graph import remap_sentinels
 
 DEGREE = 8
@@ -204,6 +205,57 @@ def test_delete_refuses_everything():
 # ---------------------------------------------------------------------------
 # satellites: knn_graph vectorization, sentinel remapping, hnsw upper ids
 # ---------------------------------------------------------------------------
+
+# ---------------------------------------------------------------------------
+# connectivity from the navigating node (NSG's tree-spanning step)
+# ---------------------------------------------------------------------------
+
+def _islands(n_islands=4, size=10, degree=3):
+    """Rings of ``size`` vertices, each vertex linked to its next
+    ``degree`` ring mates: full rows, no edge between islands."""
+    ids = np.arange(n_islands * size).reshape(n_islands, size)
+    nbrs = np.stack([np.roll(ids, -j, axis=1).reshape(-1)
+                     for j in range(1, degree + 1)], axis=1)
+    rng = np.random.RandomState(0)
+    centers = rng.randn(n_islands, DIM).astype(np.float32) * 20
+    data = (np.repeat(centers, size, axis=0)
+            + rng.randn(n_islands * size, DIM).astype(np.float32))
+    return nbrs.astype(np.int32), data
+
+
+def test_reachable_follows_out_edges():
+    nbrs, _ = _islands()
+    n = nbrs.shape[0]
+    assert reachable(nbrs, [0]).sum() == 10
+    assert reachable(nbrs, [0, 25]).sum() == 20
+    padded = np.full_like(nbrs, n)
+    assert reachable(padded, [3]).tolist() == [i == 3 for i in range(n)]
+
+
+def test_connect_from_links_every_island():
+    nbrs, data = _islands()
+    n, degree = nbrs.shape
+    before = nbrs.copy()
+    added = connect_from(nbrs, data, 0, ef=EF, metric="l2")
+    assert added == 3                       # one link per unreached island
+    assert reachable(nbrs, [0]).all()
+    assert nbrs.shape == (n, degree) and (nbrs < n).all()
+    assert (nbrs != np.arange(n)[:, None]).all()
+    assert (nbrs != before).sum() == added  # each link took one slot
+    again = nbrs.copy()
+    assert connect_from(nbrs, data, 0, ef=EF, metric="l2") == 0
+    np.testing.assert_array_equal(nbrs, again)
+
+
+def test_nsg_build_reaches_every_vertex_from_the_medoid():
+    # well-separated clusters: degree-capped pruning fills each row inside
+    # its own cluster, so only the connectivity step links the clusters
+    ds = make_vector_dataset("deep", n=1200, n_queries=16, k=10, dim=24,
+                             n_clusters=12, seed=7)
+    g = build_nsg(ds.base, degree=12, knn_k=12, ef_construction=24,
+                  passes=2)
+    assert reachable(np.asarray(g.nbrs), [int(g.medoid)]).all()
+
 
 def test_knn_graph_matches_loop_reference():
     data = _data(n=90, seed=7)

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.ann.index import quantize_graph
 from repro.core import build_nsg
 from repro.core.bfis import search_topm_batch
 from repro.core.config import SearchConfig
@@ -29,8 +30,9 @@ from repro.data import make_vector_dataset
 from repro.kernels.dedup import (dedupdist, dedupdist_int8,
                                  make_dedup_int8_dist_fn, unique_ids_inverse)
 from repro.kernels.l2dist import l2dist_rowgather
+from repro.kernels.platform import row_tiles
 from repro.kernels.ref import dist_ref
-from repro.kernels.registry import available_backends
+from repro.kernels.registry import available_backends, with_kernel_tables
 from repro.quant.codec import fit_scales, quantize
 from repro.quant.scheme import QuantSpec, required_quant_dtype
 
@@ -58,7 +60,8 @@ def graphs(ds):
         np.linalg.norm(base, axis=1, keepdims=True), 1e-12)
     out["cosine"] = build_nsg(normed, degree=10, knn_k=10,
                               ef_construction=20, passes=1)
-    return out
+    # with the row-tiled tables the Pallas backends read
+    return {m: with_kernel_tables(g, "rowgather") for m, g in out.items()}
 
 
 def queries_for(ds, metric, b):
@@ -146,15 +149,15 @@ def test_kernel_no_overlap_and_all_dup(metric):
     # no overlap: every lane's ids disjoint -> T holds all B*C of them
     ids = jnp.arange(b * c, dtype=jnp.int32).reshape(b, c)
     np.testing.assert_array_equal(
-        np.asarray(dedupdist(table, ids, q, metric=metric)),
-        np.asarray(l2dist_rowgather(table, ids, q, metric=metric)))
+        np.asarray(dedupdist(row_tiles(table), ids, q, metric=metric)),
+        np.asarray(l2dist_rowgather(row_tiles(table), ids, q, metric=metric)))
     _, _, n_uniq = unique_ids_inverse(ids, n)
     assert int(n_uniq) == b * c
     # all duplicates: one id everywhere -> a single real gather
     ids = jnp.full((b, c), 7, jnp.int32)
     np.testing.assert_array_equal(
-        np.asarray(dedupdist(table, ids, q, metric=metric)),
-        np.asarray(l2dist_rowgather(table, ids, q, metric=metric)))
+        np.asarray(dedupdist(row_tiles(table), ids, q, metric=metric)),
+        np.asarray(l2dist_rowgather(row_tiles(table), ids, q, metric=metric)))
     _, _, n_uniq = unique_ids_inverse(ids, n)
     assert int(n_uniq) == 1
 
@@ -166,9 +169,9 @@ def test_kernel_matches_ref_with_padding(metric):
     table = jnp.asarray(rng.randn(n, d), jnp.float32)
     q = jnp.asarray(rng.randn(b, d), jnp.float32)
     ids = jnp.asarray(rng.randint(0, n + 1, size=(b, c)), jnp.int32)
-    got = np.asarray(dedupdist(table, ids, q, metric=metric))
+    got = np.asarray(dedupdist(row_tiles(table), ids, q, metric=metric))
     np.testing.assert_array_equal(
-        got, np.asarray(l2dist_rowgather(table, ids, q, metric=metric)))
+        got, np.asarray(l2dist_rowgather(row_tiles(table), ids, q, metric=metric)))
     np.testing.assert_allclose(
         got, np.asarray(dist_ref(table, ids, q, metric=metric)),
         rtol=1e-5, atol=1e-5)
@@ -178,11 +181,8 @@ def test_kernel_matches_ref_with_padding(metric):
 # -- int8 variant -----------------------------------------------------------
 
 def quantized(graph, dtype="int8"):
-    spec = QuantSpec(dtype=dtype)
-    scales = fit_scales(graph.vectors, spec)
-    return graph._replace(
-        codes=quantize(graph.vectors, spec, scales),
-        scales=jnp.asarray(scales, jnp.float32))
+    return with_kernel_tables(quantize_graph(graph, QuantSpec(dtype=dtype)),
+                              "rowgather_int8")
 
 
 @pytest.mark.parametrize("metric", ["l2", "ip", "cosine"])
@@ -216,8 +216,8 @@ def test_int8_kernel_all_dup_batch64(graphs):
     q = jnp.asarray(rng.randn(b, gq.vectors.shape[1]), jnp.float32)
     from repro.quant.kernels import int8dist_rowgather
     np.testing.assert_array_equal(
-        np.asarray(dedupdist_int8(gq.codes, gq.scales, ids, q)),
-        np.asarray(int8dist_rowgather(gq.codes, gq.scales, ids, q)))
+        np.asarray(dedupdist_int8(gq.code_tiles, gq.scales, ids, q)),
+        np.asarray(int8dist_rowgather(gq.code_tiles, gq.scales, ids, q)))
 
 
 def test_int8_per_dim_scales_rejected(graphs):

@@ -16,6 +16,8 @@ from repro.data import make_vector_dataset
 from repro.kernels import (available_backends, l2dist, make_dist_fn,
                            pad_ids_to_tile, resolve_backend)
 from repro.kernels import ref as kref
+from repro.kernels.platform import row_tiles
+from repro.kernels.registry import with_kernel_tables
 
 
 @pytest.fixture(scope="module")
@@ -26,9 +28,11 @@ def ds():
 
 @pytest.fixture(scope="module")
 def graph(ds):
-    # degree chosen so M*R is NOT a multiple of the DMA tile (see below)
-    return build_nsg(ds.base, degree=12, knn_k=12, ef_construction=24,
-                     passes=1)
+    # degree chosen so M*R is NOT a multiple of the DMA tile (see below);
+    # with the row-tiled table the Pallas backends read
+    return with_kernel_tables(
+        build_nsg(ds.base, degree=12, knn_k=12, ef_construction=24,
+                  passes=1), "rowgather")
 
 
 # m_max=3, degree=12 -> C = 36 candidates per expansion, 36 % 8 != 0:
@@ -109,7 +113,7 @@ def test_dma_padding_edge_case_kernel_level():
     np.testing.assert_array_equal(np.asarray(padded[:c]), np.asarray(ids))
     assert (np.asarray(padded[c:]) == n).all()
 
-    got = l2dist(table, padded[None, :], q, impl="dma", g=g)
+    got = l2dist(row_tiles(table), padded[None, :], q, impl="dma", g=g)
     want = kref.l2dist_ref(table, ids[None, :], q)
     np.testing.assert_allclose(np.asarray(got)[0, :c], np.asarray(want)[0],
                                rtol=1e-5, atol=1e-5)

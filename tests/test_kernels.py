@@ -10,6 +10,7 @@ from hypothesis import given, settings, strategies as st  # noqa: E402
 
 from repro.kernels import l2dist, sort_pairs, topl_merge
 from repro.kernels import ref as kref
+from repro.kernels.platform import row_tiles
 from repro.core import queue as fq
 
 
@@ -31,7 +32,7 @@ def _mk(n, d, b, c, dtype, seed=0):
 @pytest.mark.parametrize("dtype", [np.float32, jnp.bfloat16])
 def test_l2dist_matches_ref(impl, n, d, b, c, dtype):
     table, ids, q = _mk(n, d, b, c, dtype)
-    got = l2dist(table, ids, q, impl=impl)
+    got = l2dist(row_tiles(table), ids, q, impl=impl)
     want = kref.l2dist_ref(table, ids, q)
     tol = 1e-5 if dtype == np.float32 else 2e-2
     np.testing.assert_allclose(
@@ -46,7 +47,7 @@ def test_l2dist_matches_ref(impl, n, d, b, c, dtype):
 @settings(max_examples=10, deadline=None)
 def test_l2dist_property_padding_and_nonneg(n, c, seed):
     table, ids, q = _mk(n, 16, 2, c, np.float32, seed=seed % 1000)
-    got = np.asarray(l2dist(table, ids, q, impl="rowgather"))
+    got = np.asarray(l2dist(row_tiles(table), ids, q, impl="rowgather"))
     # padding ids -> +inf; real ids -> finite, non-negative
     assert np.isinf(got[np.asarray(ids) >= n]).all()
     real = got[np.asarray(ids) < n]
@@ -143,6 +144,7 @@ def test_search_with_pallas_dist_fn_matches_default():
     from repro.core import bfis_search_batch, build_nsg
     from repro.data import make_vector_dataset
     from repro.kernels import make_dist_fn
+    from repro.kernels.registry import with_kernel_tables
 
     ds = make_vector_dataset("deep", n=800, n_queries=8, k=10, dim=24,
                              n_clusters=8, seed=3)
@@ -151,7 +153,8 @@ def test_search_with_pallas_dist_fn_matches_default():
     q = jnp.asarray(ds.queries)
     ids_ref, d_ref, _ = bfis_search_batch(g, q, cfg)
     ids_pal, d_pal, _ = bfis_search_batch(
-        g, q, cfg, dist_fn=make_dist_fn("rowgather"))
+        with_kernel_tables(g, "rowgather"), q, cfg,
+        dist_fn=make_dist_fn("rowgather"))
     np.testing.assert_array_equal(np.asarray(ids_ref), np.asarray(ids_pal))
     np.testing.assert_allclose(np.asarray(d_ref), np.asarray(d_pal),
                                rtol=1e-5, atol=1e-5)
@@ -189,7 +192,7 @@ def test_dedup_unique_empty_after_masking():
     assert (np.asarray(uniq) >= n).all()
     table = jnp.asarray(np.random.RandomState(0).randn(n, d), jnp.float32)
     q = jnp.asarray(np.random.RandomState(1).randn(b, d), jnp.float32)
-    assert np.isinf(np.asarray(dedupdist(table, ids, q))).all()
+    assert np.isinf(np.asarray(dedupdist(row_tiles(table), ids, q))).all()
 
 
 def test_dedup_unique_count_on_tile_boundary():
@@ -216,5 +219,5 @@ def test_dedup_n_nodes_smaller_than_tile():
     q = jnp.asarray(rng.randn(b, d), jnp.float32)
     ids = jnp.asarray(rng.randint(0, n + 2, size=(b, c)), jnp.int32)
     np.testing.assert_array_equal(
-        np.asarray(dedupdist(table, ids, q)),
-        np.asarray(l2dist_rowgather(table, ids, q)))
+        np.asarray(dedupdist(row_tiles(table), ids, q)),
+        np.asarray(l2dist_rowgather(row_tiles(table), ids, q)))

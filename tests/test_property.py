@@ -11,6 +11,7 @@ from repro.core import visited as vs
 from repro.core.metrics import batch_unique_counts, recall_at_k
 from repro.kernels.dedup import dedupdist, unique_ids_inverse
 from repro.kernels.l2dist import l2dist_rowgather
+from repro.kernels.platform import row_tiles
 
 INVALID = 2**31 - 1
 
@@ -142,8 +143,8 @@ def test_dedup_gather_scatter_extensional(seed, b, c, idmax):
     q = jnp.asarray(rng.randn(b, d), np.float32)
     # idmax+3 head-room -> some draws are padding ids (>= n)
     ids = jnp.asarray(rng.randint(0, n + 3, size=(b, c)), jnp.int32)
-    got = np.asarray(dedupdist(table, ids, q))
-    want = np.asarray(l2dist_rowgather(table, ids, q))
+    got = np.asarray(dedupdist(row_tiles(table), ids, q))
+    want = np.asarray(l2dist_rowgather(row_tiles(table), ids, q))
     np.testing.assert_array_equal(got, want)
     uniq, inv, n_uniq = unique_ids_inverse(ids, n)
     uniq, inv = np.asarray(uniq), np.asarray(inv)

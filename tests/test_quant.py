@@ -30,8 +30,10 @@ except ImportError:  # pragma: no cover - exercised on bare installs
     def settings(**kw):
         return lambda f: f
 
+from repro.ann.index import quantize_graph  # noqa: E402
 from repro.core.graph import make_padded_csr  # noqa: E402
 from repro.kernels import resolve_backend  # noqa: E402
+from repro.kernels.registry import with_kernel_tables  # noqa: E402
 from repro.config import SearchConfig  # noqa: E402
 from repro.quant import (QuantSpec, dequantize, fit_scales,  # noqa: E402
                          max_error_bound, quantize, quantize_query)
@@ -94,10 +96,8 @@ def test_zero_vectors_quantize_cleanly():
 def quantized_graph(x, spec):
     n = x.shape[0]
     nbrs = np.tile(np.arange(n, dtype=np.int32)[None, :8], (n, 1))
-    g = make_padded_csr(nbrs, x)
-    scales = fit_scales(x, spec)
-    return g._replace(codes=quantize(x, spec, scales),
-                      scales=jnp.asarray(scales, jnp.float32))
+    return with_kernel_tables(quantize_graph(make_padded_csr(nbrs, x), spec),
+                              "rowgather_int8")
 
 
 def exact_dist(x, q, metric):
@@ -157,7 +157,7 @@ def test_rowgather_int8_matches_ref_int8(metric):
     g = quantized_graph(x, spec)
     ids = jnp.asarray(
         np.random.RandomState(5).randint(0, 44, size=(2, 12)), jnp.int32)
-    got = np.asarray(int8dist_rowgather(g.codes, g.scales, ids,
+    got = np.asarray(int8dist_rowgather(g.code_tiles, g.scales, ids,
                                         jnp.asarray(q), metric=metric))
     ref_fn = resolve_backend(SearchConfig(metric=metric,
                                           dist_backend="ref_int8"))
