@@ -170,7 +170,8 @@ def sweep(out_path: str = "BENCH_serve.json", n: int = 2000, q: int = 32,
     With ``trace_out`` the HIGHEST-QPS sweep point runs with request-scoped
     tracing on and dumps its Chrome-trace/Perfetto JSON there — the point
     where coalescing actually forms multi-request batches, so the trace
-    shows nested batch_formation → dispatch → device_compute spans.
+    shows coalescer.form → engine.search → engine.dispatch/sync/readback
+    spans.
     Tracing stays off for every other point (and entirely without
     ``trace_out``), so the sweep's latency numbers are untraced.
 

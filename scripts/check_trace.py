@@ -19,7 +19,7 @@ Validates the file a ``--trace-out`` run writes (``examples/serve_ann.py``,
   closed by an ``e`` (and vice versa), with begin <= end timestamps.
 * **--require NAME** (repeatable) — at least one event with that name
   exists; the CI smoke requires the span names the serving stack promises
-  (``batch_formation``, ``dispatch``, ``device_compute``...).
+  (``coalescer.form``, ``engine.search``, ``engine.dispatch``...).
 
 Exit code 0 when the trace is well-formed (a per-check summary is
 printed); 1 with a report otherwise.  Stdlib only, so CI can run it

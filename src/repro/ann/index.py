@@ -588,6 +588,7 @@ class AnnIndex:
         device-resident embedding table.  Query normalization (cosine) and
         grouping id-remap run inside the jitted function.  Searchers are
         cached per (params, mesh) — repeated ``search`` calls reuse them.
+        ``fn.lower(queries)`` lowers the same executable for inspection.
         """
         key = (params, id(mesh) if mesh is not None else None)
         cached = self._searcher_cache.get(key)
@@ -674,7 +675,8 @@ class AnnIndex:
                 # the AQR-HNSW two-stage shape: quantized (or plain) best-
                 # first traversal, then exact f32 re-ranking of the pool —
                 # in internal id space, BEFORE the grouping remap
-                ids, dists = exact_rerank(g, q, ids, k, metric)
+                with jax.named_scope("ann.rerank"):
+                    ids, dists = exact_rerank(g, q, ids, k, metric)
             if has_remap:
                 ids = remap_result_ids(ids, ofn_arr, n_nodes)
             return ids, dists, stats
@@ -691,6 +693,16 @@ class AnnIndex:
                          q)
             return SearchResult(*out)
 
+        def lower(queries):
+            """The search's ``jax.stages.Lowered`` for ``queries``: its
+            ``.compile().as_text()`` is the HLO that runs, each
+            instruction's ``op_name`` naming its ``ann.*`` scope."""
+            return jitted.lower(graph.nbrs, graph.vectors, graph.medoid,
+                                graph.flat, graph.codes, graph.scales,
+                                graph.vector_tiles, graph.code_tiles, ofn,
+                                tomb, jnp.asarray(queries))
+
+        fn.lower = lower
         self._searcher_cache[key] = fn
         return fn
 

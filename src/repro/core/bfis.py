@@ -105,14 +105,22 @@ def point_dist(v: jax.Array, q: jax.Array, metric: str = "l2") -> jax.Array:
     return jnp.sum((v - q) ** 2, axis=-1)
 
 
-def lane_select(alive: jax.Array, new, old):
+def lane_select(alive: jax.Array, new: NamedTuple, old: NamedTuple,
+                scopes: Tuple[str, ...]):
     """Per-lane carry masking: where ``alive[b]`` take ``new``, else keep
     ``old`` — the ``jax.vmap`` while_loop batching rule, applied explicitly
-    by the batch-major engine so converged queries are exact no-ops."""
+    by the batch-major engine so converged queries are exact no-ops.  Each
+    field of the carry is masked under the named scope of the phase that
+    owns it (``scopes``, aligned with the fields)."""
     def sel(n, o):
         pred = alive.reshape(alive.shape + (1,) * (n.ndim - alive.ndim))
         return jnp.where(pred, n, o)
-    return jax.tree.map(sel, new, old)
+    out = []
+    for field, scope in zip(new._fields, scopes, strict=True):
+        with jax.named_scope(scope):
+            out.append(jax.tree.map(sel, getattr(new, field),
+                                    getattr(old, field)))
+    return type(new)(*out)
 
 
 def expand_batch(
@@ -141,25 +149,32 @@ def expand_batch(
     invariant).
     """
     bsz = queries.shape[0]
-    frontier, active_ids, active_valid = fq.select_unchecked_batch(
-        frontier, m_max, m)
-    nbrs = gather_neighbor_ids(graph, active_ids)          # (B, m_max, R)
-    flat = nbrs.reshape(bsz, -1)
-    valid = (flat < graph.n_nodes) \
-        & jnp.repeat(active_valid, graph.degree, axis=-1)
-    visited, fresh = vs.check_and_insert_batch(visited, flat, valid)
-    # the frontier stores f32 keys; normalize here so a backend that reduces
-    # in another precision (int32-accumulated int8, bf16) can't leak its
-    # accumulator dtype into the queue
-    dists = dist_fn(graph, active_ids, nbrs, queries).astype(
-        jnp.float32).reshape(bsz, -1)
-    dists = jnp.where(fresh, dists, jnp.inf)
-    cand_ids = jnp.where(fresh, flat, fq.INVALID_ID)
-    frontier, up_pos, _ = fq.insert_batch(frontier, cand_ids, dists)
-    counted = fresh if lane_mask is None else fresh & lane_mask[:, None]
-    n_uniq = batch_unique_counts(flat, counted)
-    return frontier, visited, up_pos, \
-        jnp.sum(fresh, axis=-1).astype(jnp.int32), n_uniq
+    with jax.named_scope("ann.select"):
+        frontier, active_ids, active_valid = fq.select_unchecked_batch(
+            frontier, m_max, m)
+    with jax.named_scope("ann.neighbors"):
+        nbrs = gather_neighbor_ids(graph, active_ids)      # (B, m_max, R)
+        flat = nbrs.reshape(bsz, -1)
+        valid = (flat < graph.n_nodes) \
+            & jnp.repeat(active_valid, graph.degree, axis=-1)
+    with jax.named_scope("ann.visited"):
+        visited, fresh = vs.check_and_insert_batch(visited, flat, valid)
+    with jax.named_scope("ann.distance"):
+        # the frontier stores f32 keys; normalize here so a backend that
+        # reduces in another precision (int32-accumulated int8, bf16) can't
+        # leak its accumulator dtype into the queue
+        dists = dist_fn(graph, active_ids, nbrs, queries).astype(
+            jnp.float32).reshape(bsz, -1)
+    with jax.named_scope("ann.queue"):
+        dists = jnp.where(fresh, dists, jnp.inf)
+        cand_ids = jnp.where(fresh, flat, fq.INVALID_ID)
+        frontier, up_pos, _ = fq.insert_batch(frontier, cand_ids, dists)
+    with jax.named_scope("ann.counters"):
+        counted = fresh if lane_mask is None \
+            else fresh & lane_mask[:, None]
+        n_uniq = batch_unique_counts(flat, counted)
+        n_comps = jnp.sum(fresh, axis=-1).astype(jnp.int32)
+    return frontier, visited, up_pos, n_comps, n_uniq
 
 
 def expand(
@@ -216,22 +231,31 @@ def _init_state_batch(
     """Batch-major initial state for (B, d) queries: frontier (B, L),
     visited (B, ...), stats leaves (B,), seeded at the entry point."""
     bsz = queries.shape[0]
-    frontier = fq.make_frontier_batch(cfg.queue_len, bsz)
-    visited = vs.make_visited_batch(cfg.visited_mode, graph.n_nodes, bsz,
-                                    cfg.hash_bits)
-    s = _seed_ids(graph, start, bsz)
-    visited, _ = vs.check_and_insert_batch(
-        visited, s[:, None], jnp.ones((bsz, 1), bool))
-    v = graph.vectors[s].astype(jnp.float32)               # (B, d)
-    d0 = point_dist(v, queries, cfg.metric)[:, None]
-    frontier, _, _ = fq.insert_batch(frontier, s[:, None], d0)
-    # the seed computation participates in first-toucher accounting too: a
-    # shared entry point (the medoid) is the batch's first overlapping row
-    seed_uniq = batch_unique_counts(s[:, None], jnp.ones((bsz, 1), bool))
-    stats = SearchStats.zero_batch(bsz)._replace(
-        dist_comps=jnp.ones((bsz,), jnp.int32),
-        uniq_comps=seed_uniq,
-        batch_dup_comps=jnp.int32(1) - seed_uniq)
+    with jax.named_scope("ann.queue"):
+        frontier = fq.make_frontier_batch(cfg.queue_len, bsz)
+    with jax.named_scope("ann.visited"):
+        visited = vs.make_visited_batch(cfg.visited_mode, graph.n_nodes,
+                                        bsz, cfg.hash_bits)
+    with jax.named_scope("ann.select"):
+        s = _seed_ids(graph, start, bsz)
+    with jax.named_scope("ann.visited"):
+        visited, _ = vs.check_and_insert_batch(
+            visited, s[:, None], jnp.ones((bsz, 1), bool))
+    with jax.named_scope("ann.distance"):
+        v = graph.vectors[s].astype(jnp.float32)           # (B, d)
+        d0 = point_dist(v, queries, cfg.metric)[:, None]
+    with jax.named_scope("ann.queue"):
+        frontier, _, _ = fq.insert_batch(frontier, s[:, None], d0)
+    with jax.named_scope("ann.counters"):
+        # the seed computation participates in first-toucher accounting
+        # too: a shared entry point (the medoid) is the batch's first
+        # overlapping row
+        seed_uniq = batch_unique_counts(s[:, None],
+                                        jnp.ones((bsz, 1), bool))
+        stats = SearchStats.zero_batch(bsz)._replace(
+            dist_comps=jnp.ones((bsz,), jnp.int32),
+            uniq_comps=seed_uniq,
+            batch_dup_comps=jnp.int32(1) - seed_uniq)
     return _TopMState(frontier, visited, stats)
 
 
@@ -260,31 +284,40 @@ def _run_topm_batch(
     st = _init_state_batch(graph, queries, cfg, start)
 
     def lanes_live(s: _TopMState) -> jax.Array:
-        return fq.has_unchecked_batch(s.frontier) \
-            & (s.stats.steps < cfg.max_steps)
+        with jax.named_scope("ann.queue"):
+            return fq.has_unchecked_batch(s.frontier) \
+                & (s.stats.steps < cfg.max_steps)
 
     def cond(s: _TopMState):
         return jnp.any(lanes_live(s))
 
     def body(s: _TopMState):
         alive = lanes_live(s)
-        live = fq.has_unchecked_batch(s.frontier).astype(jnp.int32)
-        m = staged_m(s.stats.steps, cfg)
+        with jax.named_scope("ann.queue"):
+            live = fq.has_unchecked_batch(s.frontier).astype(jnp.int32)
+        with jax.named_scope("ann.select"):
+            m = staged_m(s.stats.steps, cfg)
         frontier, visited, _, n, uniq = expand_batch(
             graph, queries, s.frontier, s.visited, cfg.m_max, m, dist_fn,
             lane_mask=alive)
-        stats = s.stats._replace(
-            steps=s.stats.steps + live,
-            local_steps=s.stats.local_steps
-            + jnp.minimum(m, jnp.int32(cfg.m_max)) * live,
-            dist_comps=s.stats.dist_comps + n,
-            uniq_comps=s.stats.uniq_comps + uniq,
-            batch_dup_comps=s.stats.batch_dup_comps + (n - uniq),
-            crit_rounds=s.stats.crit_rounds + live,
-        )
-        return lane_select(alive, _TopMState(frontier, visited, stats), s)
+        with jax.named_scope("ann.counters"):
+            stats = s.stats._replace(
+                steps=s.stats.steps + live,
+                local_steps=s.stats.local_steps
+                + jnp.minimum(m, jnp.int32(cfg.m_max)) * live,
+                dist_comps=s.stats.dist_comps + n,
+                uniq_comps=s.stats.uniq_comps + uniq,
+                batch_dup_comps=s.stats.batch_dup_comps + (n - uniq),
+                crit_rounds=s.stats.crit_rounds + live,
+            )
+        return lane_select(
+            alive, _TopMState(frontier, visited, stats), s,
+            ("ann.queue", "ann.visited", "ann.counters"))
 
-    return jax.lax.while_loop(cond, body, st)
+    # the loop's own control (its while op, the carry copies XLA inserts)
+    # under ann.loop; the phases inside keep their own scopes
+    with jax.named_scope("ann.loop"):
+        return jax.lax.while_loop(cond, body, st)
 
 
 def search_topm_batch(
@@ -303,7 +336,8 @@ def search_topm_batch(
     Algorithm 1 exactly.  Returns (ids (B, k), dists (B, k), stats (B,)).
     """
     st = _run_topm_batch(graph, queries, cfg, start, dist_fn)
-    ids, dists = fq.results_batch(st.frontier, cfg.k)
+    with jax.named_scope("ann.queue"):
+        ids, dists = fq.results_batch(st.frontier, cfg.k)
     return ids, dists, st.stats
 
 

@@ -92,7 +92,8 @@ def _local_segment_batch(
     w = cfg.num_walkers
     cap = cfg.queue_len
     bsz = queries.shape[0]
-    q_rep = jnp.repeat(queries, w, axis=0)                 # (B·W, d)
+    with jax.named_scope("ann.distance"):
+        q_rep = jnp.repeat(queries, w, axis=0)             # (B·W, d)
 
     def flatten_bw(t):
         return t.reshape((bsz * w,) + t.shape[2:])
@@ -104,38 +105,55 @@ def _local_segment_batch(
         return jnp.arange(w)[None, :] < active[:, None]    # (B, W)
 
     def lanes_live(s: _LocalState) -> jax.Array:
-        any_work = jnp.any(
-            fq.has_unchecked_batch(s.locals_) & is_active_mask(), axis=-1)
-        return (~s.do_merge) & any_work & (s.lstep < cfg.local_steps)
+        with jax.named_scope("ann.queue"):
+            any_work = jnp.any(
+                fq.has_unchecked_batch(s.locals_) & is_active_mask(),
+                axis=-1)
+            return (~s.do_merge) & any_work & (s.lstep < cfg.local_steps)
 
     def cond(s: _LocalState):
         return jnp.any(lanes_live(s))
 
     def body(s: _LocalState):
         alive = lanes_live(s)
-        counted_q = alive if query_mask is None else alive & query_mask
-        had_work = fq.has_unchecked_batch(s.locals_) & is_active_mask()
-        # ONE batch-major expansion over all B·W walker lanes (M=1 each)
-        fr = jax.tree.map(flatten_bw, s.locals_)
-        vis = jax.tree.map(flatten_bw, s.visited)
+        with jax.named_scope("ann.queue"):
+            counted_q = alive if query_mask is None \
+                else alive & query_mask
+            had_work = fq.has_unchecked_batch(s.locals_) & is_active_mask()
+            # ONE batch-major expansion over all B·W walker lanes (M=1 each)
+            fr = jax.tree.map(flatten_bw, s.locals_)
+        with jax.named_scope("ann.visited"):
+            vis = jax.tree.map(flatten_bw, s.visited)
+        with jax.named_scope("ann.counters"):
+            lane_mask = jnp.repeat(counted_q, w)
         fr, vis, up, n, uniq = expand_batch(
-            graph, q_rep, fr, vis, 1, 1, dist_fn,
-            lane_mask=jnp.repeat(counted_q, w))
-        locals2 = jax.tree.map(unflatten_bw, fr)
-        visited2 = jax.tree.map(unflatten_bw, vis)
-        up = up.reshape(bsz, w)
-        n = n.reshape(bsz, w)
-        uniq = uniq.reshape(bsz, w)
-        # walkers with no unchecked candidates saturate at L (stuck)
-        up = jnp.where(had_work, up, cap).astype(jnp.int32)
-        do_merge = jax.vmap(
-            lambda u, a: check_metrics(u, a, cfg))(up, active)
+            graph, q_rep, fr, vis, 1, 1, dist_fn, lane_mask=lane_mask)
+        with jax.named_scope("ann.queue"):
+            locals2 = jax.tree.map(unflatten_bw, fr)
+        with jax.named_scope("ann.visited"):
+            visited2 = jax.tree.map(unflatten_bw, vis)
+        with jax.named_scope("ann.queue"):
+            up = up.reshape(bsz, w)
+        with jax.named_scope("ann.counters"):
+            n = n.reshape(bsz, w)
+            uniq = uniq.reshape(bsz, w)
+        with jax.named_scope("ann.queue"):
+            # walkers with no unchecked candidates saturate at L (stuck)
+            up = jnp.where(had_work, up, cap).astype(jnp.int32)
+            # Algorithm 2 on the walkers' update positions
+            do_merge = jax.vmap(
+                lambda u, a: check_metrics(u, a, cfg))(up, active)
+            lstep = s.lstep + 1
+        with jax.named_scope("ann.counters"):
+            comps = s.comps + jnp.sum(jnp.where(had_work, n, 0), axis=-1)
+            uniq = s.uniq + jnp.sum(jnp.where(had_work, uniq, 0), axis=-1)
         new = _LocalState(
             locals_=locals2, visited=visited2, up_pos=up,
-            lstep=s.lstep + 1, do_merge=do_merge,
-            comps=s.comps + jnp.sum(jnp.where(had_work, n, 0), axis=-1),
-            uniq=s.uniq + jnp.sum(jnp.where(had_work, uniq, 0), axis=-1))
-        return lane_select(alive, new, s)
+            lstep=lstep, do_merge=do_merge, comps=comps, uniq=uniq)
+        return lane_select(
+            alive, new, s,
+            ("ann.queue", "ann.visited", "ann.queue", "ann.queue",
+             "ann.queue", "ann.counters", "ann.counters"))
 
     init = _LocalState(
         locals_=locals_, visited=visited,
@@ -144,7 +162,8 @@ def _local_segment_batch(
         do_merge=jnp.zeros((bsz,), bool),
         comps=jnp.zeros((bsz,), jnp.int32),
         uniq=jnp.zeros((bsz,), jnp.int32))
-    out = jax.lax.while_loop(cond, body, init)
+    with jax.named_scope("ann.loop"):
+        out = jax.lax.while_loop(cond, body, init)
     return out.locals_, out.visited, out.lstep, out.comps, out.uniq
 
 
@@ -164,15 +183,21 @@ def search_speedann_batch(
     w, cap = cfg.num_walkers, cfg.queue_len
     bsz = queries.shape[0]
 
-    frontier = fq.make_frontier_batch(cap, bsz)
-    visited0 = vs.make_visited_batch(cfg.visited_mode, graph.n_nodes, bsz,
-                                     cfg.hash_bits)
-    s0 = _seed_ids(graph, start, bsz)
-    visited0, _ = vs.check_and_insert_batch(
-        visited0, s0[:, None], jnp.ones((bsz, 1), bool))
-    v0 = graph.vectors[s0].astype(jnp.float32)
-    d0 = point_dist(v0, queries, cfg.metric)[:, None]
-    frontier, _, _ = fq.insert_batch(frontier, s0[:, None], d0)
+    with jax.named_scope("ann.queue"):
+        frontier = fq.make_frontier_batch(cap, bsz)
+    with jax.named_scope("ann.visited"):
+        visited0 = vs.make_visited_batch(cfg.visited_mode, graph.n_nodes,
+                                         bsz, cfg.hash_bits)
+    with jax.named_scope("ann.select"):
+        s0 = _seed_ids(graph, start, bsz)
+    with jax.named_scope("ann.visited"):
+        visited0, _ = vs.check_and_insert_batch(
+            visited0, s0[:, None], jnp.ones((bsz, 1), bool))
+    with jax.named_scope("ann.distance"):
+        v0 = graph.vectors[s0].astype(jnp.float32)
+        d0 = point_dist(v0, queries, cfg.metric)[:, None]
+    with jax.named_scope("ann.queue"):
+        frontier, _, _ = fq.insert_batch(frontier, s0[:, None], d0)
     # Expand the starting point once before dividing work, so the first
     # scatter has a full frontier to distribute (paper Fig. 4: the search
     # fans out from P's neighbors; without this, NoSync would degenerate to
@@ -180,21 +205,25 @@ def search_speedann_batch(
     frontier, visited0, _, n0, uniq0 = expand_batch(
         graph, queries, frontier, visited0, 1, 1, dist_fn)
     # replicate the seed visited map to all walkers (consistent at t=0)
-    visited = jax.tree.map(
-        lambda t: jnp.broadcast_to(t[:, None], (bsz, w) + t.shape[1:]),
-        visited0)
+    with jax.named_scope("ann.visited"):
+        visited = jax.tree.map(
+            lambda t: jnp.broadcast_to(t[:, None], (bsz, w) + t.shape[1:]),
+            visited0)
 
-    seed_uniq = batch_unique_counts(s0[:, None], jnp.ones((bsz, 1), bool))
-    init = _GlobalState(
-        frontier=frontier, visited=visited,
-        stats=SearchStats.zero_batch(bsz)._replace(
-            dist_comps=jnp.int32(1) + n0,
-            uniq_comps=seed_uniq + uniq0,
-            batch_dup_comps=(jnp.int32(1) - seed_uniq) + (n0 - uniq0)))
+    with jax.named_scope("ann.counters"):
+        seed_uniq = batch_unique_counts(s0[:, None],
+                                        jnp.ones((bsz, 1), bool))
+        init = _GlobalState(
+            frontier=frontier, visited=visited,
+            stats=SearchStats.zero_batch(bsz)._replace(
+                dist_comps=jnp.int32(1) + n0,
+                uniq_comps=seed_uniq + uniq0,
+                batch_dup_comps=(jnp.int32(1) - seed_uniq) + (n0 - uniq0)))
 
     def lanes_live(s: _GlobalState) -> jax.Array:
-        return fq.has_unchecked_batch(s.frontier) \
-            & (s.stats.steps < cfg.max_steps)
+        with jax.named_scope("ann.queue"):
+            return fq.has_unchecked_batch(s.frontier) \
+                & (s.stats.steps < cfg.max_steps)
 
     def cond(s: _GlobalState):
         return jnp.any(lanes_live(s))
@@ -202,38 +231,50 @@ def search_speedann_batch(
     def body(s: _GlobalState):
         # invariant: s.visited is OR-merged (all walkers agree) on entry
         alive = lanes_live(s)
-        live = fq.has_unchecked_batch(s.frontier).astype(jnp.int32)
-        m = jnp.minimum(staged_m(s.stats.steps, cfg).astype(jnp.int32), w)
-        union_before = jax.vmap(vs.popcount)(s.visited)
-        # Line 7: divide unchecked candidates among active walkers.
-        locals_ = jax.vmap(
-            lambda f, a: fq.scatter_round_robin(f, w, a))(s.frontier, m)
+        with jax.named_scope("ann.queue"):
+            live = fq.has_unchecked_batch(s.frontier).astype(jnp.int32)
+        with jax.named_scope("ann.select"):
+            m = jnp.minimum(staged_m(s.stats.steps, cfg).astype(jnp.int32),
+                            w)
+        with jax.named_scope("ann.visited"):
+            union_before = jax.vmap(vs.popcount)(s.visited)
+        with jax.named_scope("ann.select"):
+            # Line 7: divide unchecked candidates among active walkers.
+            locals_ = jax.vmap(
+                lambda f, a: fq.scatter_round_robin(f, w, a))(s.frontier, m)
         # Lines 11–22: collective-free local searches + CheckMetrics.
         locals_, visited, rounds, comps, uniq = _local_segment_batch(
             graph, queries, locals_, s.visited, m, cfg, dist_fn,
             query_mask=alive)
         # Line 23: merge local queues into the global queue; §4.4: visited
         # maps reach eventual consistency here.
-        merged, _ = jax.vmap(fq.merge_frontiers)(locals_)
-        visited = jax.vmap(vs.merge_visited)(visited)
-        # cross-walker duplicate computations = work minus union growth
-        n_dups = comps - (jax.vmap(vs.popcount)(visited) - union_before)
-        stats = s.stats._replace(
-            steps=s.stats.steps + live,
-            local_steps=s.stats.local_steps + rounds * m,
-            dist_comps=s.stats.dist_comps + comps,
-            dup_comps=s.stats.dup_comps + jnp.maximum(n_dups, 0),
-            syncs=s.stats.syncs + live,
-            crit_rounds=s.stats.crit_rounds + rounds,
-            uniq_comps=s.stats.uniq_comps + uniq,
-            batch_dup_comps=s.stats.batch_dup_comps + (comps - uniq),
-        )
+        with jax.named_scope("ann.queue"):
+            merged, _ = jax.vmap(fq.merge_frontiers)(locals_)
+        with jax.named_scope("ann.visited"):
+            visited = jax.vmap(vs.merge_visited)(visited)
+            union_after = jax.vmap(vs.popcount)(visited)
+        with jax.named_scope("ann.counters"):
+            # cross-walker duplicate computations = work minus union growth
+            n_dups = comps - (union_after - union_before)
+            stats = s.stats._replace(
+                steps=s.stats.steps + live,
+                local_steps=s.stats.local_steps + rounds * m,
+                dist_comps=s.stats.dist_comps + comps,
+                dup_comps=s.stats.dup_comps + jnp.maximum(n_dups, 0),
+                syncs=s.stats.syncs + live,
+                crit_rounds=s.stats.crit_rounds + rounds,
+                uniq_comps=s.stats.uniq_comps + uniq,
+                batch_dup_comps=s.stats.batch_dup_comps + (comps - uniq),
+            )
         return lane_select(
             alive, _GlobalState(frontier=merged, visited=visited,
-                                stats=stats), s)
+                                stats=stats), s,
+            ("ann.queue", "ann.visited", "ann.counters"))
 
-    out = jax.lax.while_loop(cond, body, init)
-    ids, dists = fq.results_batch(out.frontier, cfg.k)
+    with jax.named_scope("ann.loop"):
+        out = jax.lax.while_loop(cond, body, init)
+    with jax.named_scope("ann.queue"):
+        ids, dists = fq.results_batch(out.frontier, cfg.k)
     return ids, dists, out.stats
 
 

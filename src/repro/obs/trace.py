@@ -155,7 +155,7 @@ class TraceRecorder:
 
     def span(self, name: str, cat: str = "serve",
              args: Optional[dict] = None):
-        """``with rec.span("dispatch") as sp: ...`` — duration event on the
+        """``with rec.span("engine.sync") as sp: ...`` — duration event on the
         calling thread; nested calls nest by containment."""
         if not self.enabled:
             return _NULL_SPAN

@@ -70,7 +70,7 @@ from repro.core.distributed import ShardedIndex, corpus_engine_searcher
 from repro.core.metrics import SearchStats, recall_at_k, telemetry_per_lane
 from repro.core.speedann import search_speedann_batch
 from repro.kernels.registry import with_kernel_tables
-from repro.obs import NULL_OBS, LogHistogram, Observability, device_annotation
+from repro.obs import NULL_OBS, LogHistogram, Observability
 
 DEFAULT_BUCKETS = (1, 2, 4, 8, 16, 32, 64)
 
@@ -91,9 +91,34 @@ class ServeResult(NamedTuple):
     """One served request: results sliced back to the request's true size."""
     ids: np.ndarray          # (B, k) int32
     dists: np.ndarray        # (B, k) float32
-    stats: SearchStats       # per-query counters, leaves shaped (B,)
+    stats: SearchStats       # per-query counters, NumPy leaves (B,)
     latency_ms: float        # wall clock for this request (all chunks)
     buckets: Tuple[int, ...]  # bucket(s) the request was quantized to
+
+
+@jax.jit
+def _pack_outputs(ids, dists, stats):
+    """A search's (ids (B, k), dists (B, k), stats) as ONE (B, 2k + 8)
+    int32 device array, the distances' bits unchanged, so the batch comes
+    back to the host in a single transfer."""
+    cols = [ids.astype(jnp.int32),
+            jax.lax.bitcast_convert_type(dists.astype(jnp.float32),
+                                         jnp.int32)]
+    cols += [leaf.astype(jnp.int32)[:, None] for leaf in stats]
+    return jnp.concatenate(cols, axis=1)
+
+
+def _unpack_outputs(packed: np.ndarray, size: int
+                    ) -> Tuple[np.ndarray, np.ndarray, SearchStats]:
+    """Host inverse of :func:`_pack_outputs` over the first ``size`` rows
+    (the chunk's true queries; the rest are bucket padding)."""
+    rows = packed[:size]
+    k = (packed.shape[1] - len(SearchStats._fields)) // 2
+    ids = np.ascontiguousarray(rows[:, :k])
+    dists = np.ascontiguousarray(rows[:, k:2 * k]).view(np.float32)
+    stats = SearchStats(*(np.ascontiguousarray(rows[:, 2 * k + i])
+                          for i in range(len(SearchStats._fields))))
+    return ids, dists, stats
 
 
 def _mesh_data_size(mesh) -> int:
@@ -266,6 +291,10 @@ class AnnEngine:
         self.dist_comps_total = 0
         self.uniq_comps_total = 0
         self.batch_dup_comps_total = 0
+        # lane occupancy: steps taken by served lanes, and iterations of
+        # the batch-major outer loop (per chunk, its slowest lane's steps)
+        self.lane_steps_total = 0
+        self.loop_iters_total = 0
 
     # -- jit cache ---------------------------------------------------------
 
@@ -345,7 +374,7 @@ class AnnEngine:
         for b in self.bucket_sizes:
             q = jnp.zeros((b, dim), jnp.float32)
             t0 = time.perf_counter()
-            jax.block_until_ready(self._compiled(b)(q)[0])
+            jax.block_until_ready(_pack_outputs(*self._compiled(b)(q)))
             out[b] = time.perf_counter() - t0
         self.cache_hits, self.cache_misses = hits, misses
         self._bucket_hists = {}
@@ -353,57 +382,40 @@ class AnnEngine:
 
     # -- serving -----------------------------------------------------------
 
-    def _run_chunk(self, queries: jax.Array, record: bool
-                   ) -> Tuple[tuple, int]:
-        """Pad one chunk (chunk size <= top bucket) to its bucket and run.
-
-        With ``record`` the chunk is synced (block_until_ready) and its wall
-        time lands in the per-bucket latency distribution.  Multi-chunk
-        requests pass ``record=False``: blocking between chunks would
-        serialize their dispatch, so they stay pipelined and contribute to
-        the request-level distribution only.
-        """
-        b = queries.shape[0]
-        bucket = self.bucket_for(b)
-        pad = bucket - b
-        if pad:
-            # pad with replicas of the first query: real topology, no risk
-            # of a degenerate all-zeros search dominating the vmapped loop
-            queries = jnp.concatenate(
-                [queries, jnp.broadcast_to(queries[:1],
-                                           (pad, queries.shape[1]))])
-            self.padded_queries += pad
+    def _run_chunk(self, queries, bucket: int) -> jax.Array:
+        """Upload and pad one chunk (chunk size <= top bucket) to its
+        bucket, and enqueue its search; returns the search's outputs packed
+        into one device array (``_pack_outputs``), not yet synced."""
         obs = self.obs
-        rerank_k = self.params.rerank_k if self.params is not None else 0
-        # the rerank pass (params.rerank_k > 0) runs INSIDE this compiled
-        # program, so it is part of the device_compute span, not a separate
-        # host span — the span args record it for the trace reader
-        with obs.tracer.span("device_compute", cat="engine",
-                             args={"bucket": bucket, "pad": pad,
-                                   "rerank_k": rerank_k}):
-            with device_annotation(
-                    f"ann_dispatch/bucket{bucket}", enabled=obs.profile):
-                t0 = time.perf_counter()
-                ids, dists, stats = self._compiled(bucket)(queries)
-                if record:
-                    jax.block_until_ready(ids)
-                    hist = self._bucket_hists.get(bucket)
-                    if hist is None:
-                        hist = self._bucket_hists.setdefault(
-                            bucket, LogHistogram(rel_err=LATENCY_REL_ERR))
-                    hist.observe((time.perf_counter() - t0) * 1e3)
-        out = (ids[:b], dists[:b],
-               jax.tree.map(lambda t: t[:b], stats))
-        return out, bucket
+        b = queries.shape[0]
+        pad = bucket - b
+        with obs.span("engine.pad", cat="engine", bucket=bucket, pad=pad):
+            queries = jnp.asarray(queries)
+            if pad:
+                # pad with replicas of the first query: real topology, no
+                # risk of a degenerate all-zeros search dominating the loop
+                queries = jnp.concatenate(
+                    [queries, jnp.broadcast_to(queries[:1],
+                                               (pad, queries.shape[1]))])
+                self.padded_queries += pad
+        # the rerank pass (params.rerank_k > 0) runs INSIDE the compiled
+        # program, so it is part of this dispatch, not a separate host span
+        with obs.span("engine.dispatch", cat="engine", bucket=bucket,
+                      rerank_k=(self.params.rerank_k
+                                if self.params is not None else 0)):
+            return _pack_outputs(*self._compiled(bucket)(queries))
 
     def search(self, queries, gt_ids: Optional[np.ndarray] = None
                ) -> ServeResult:
         """Serve one request of (B, d) queries, any B >= 1.
 
         With ``gt_ids`` (B, >=k) the engine also folds recall@k into its
-        running quality counters.
+        running quality counters.  Every chunk's ids, distances and
+        ``SearchStats`` come back to the host in one transfer; the
+        result's arrays are NumPy.
         """
-        queries = jnp.asarray(queries)
+        if not isinstance(queries, jax.Array):
+            queries = np.asarray(queries)
         if queries.ndim != 2 or queries.shape[0] == 0:
             raise ValueError(
                 f"queries must be (B, d) with B >= 1, got {queries.shape}")
@@ -411,48 +423,57 @@ class AnnEngine:
         top = self.bucket_sizes[-1]
         obs = self.obs
 
-        with obs.tracer.span("engine.search", cat="engine",
-                             args={"batch": bsz}) as sp:
+        with obs.span("engine.search", cat="engine", size=bsz) as sp:
             t0 = time.perf_counter()
-            chunks, buckets = [], []
-            single_chunk = bsz <= top
-            for lo in range(0, bsz, top):
-                out, bucket = self._run_chunk(queries[lo:lo + top],
-                                              record=single_chunk)
-                chunks.append(out)
-                buckets.append(bucket)
-            if not single_chunk:
-                jax.block_until_ready(chunks[-1][0])
+            sizes = [min(top, bsz - lo) for lo in range(0, bsz, top)]
+            buckets = [self.bucket_for(n) for n in sizes]
+            packed = [self._run_chunk(queries[lo:lo + n], bucket)
+                      for lo, n, bucket in zip(range(0, bsz, top), sizes,
+                                               buckets)]
+            with obs.span("engine.sync", cat="engine"):
+                jax.block_until_ready(packed)
             ms = (time.perf_counter() - t0) * 1e3
+            if len(packed) == 1:
+                # per-bucket rows cover single-chunk requests only
+                hist = self._bucket_hists.get(buckets[0])
+                if hist is None:
+                    hist = self._bucket_hists.setdefault(
+                        buckets[0], LogHistogram(rel_err=LATENCY_REL_ERR))
+                hist.observe(ms)
             sp.add_args(buckets=list(buckets), latency_ms=round(ms, 3))
+            with obs.span("engine.readback", cat="engine",
+                          arrays=len(packed),
+                          bytes=sum(p.nbytes for p in packed)):
+                host = jax.device_get(packed)
 
-            with obs.tracer.span("postprocess", cat="engine"):
-                if len(chunks) == 1:
-                    ids, dists, stats = chunks[0]
-                else:
-                    ids = jnp.concatenate([c[0] for c in chunks])
-                    dists = jnp.concatenate([c[1] for c in chunks])
-                    stats = jax.tree.map(
-                        lambda *xs: jnp.concatenate(xs), *[c[2] for c in chunks])
-
+            with obs.span("engine.postprocess", cat="engine"):
+                parts = [_unpack_outputs(h, n) for h, n in zip(host, sizes)]
+                ids, dists, stats = parts[0]
+                if len(parts) > 1:
+                    ids = np.concatenate([p[0] for p in parts])
+                    dists = np.concatenate([p[1] for p in parts])
+                    stats = SearchStats(*(np.concatenate(xs) for xs in
+                                          zip(*(p[2] for p in parts))))
                 self.queries_served += bsz
                 self.requests_served += 1
                 self._latency_hist.observe(ms)
-                self.dist_comps_total += int(
-                    np.sum(np.asarray(stats.dist_comps)))
-                self.uniq_comps_total += int(
-                    np.sum(np.asarray(stats.uniq_comps)))
+                self.dist_comps_total += int(np.sum(stats.dist_comps))
+                self.uniq_comps_total += int(np.sum(stats.uniq_comps))
                 self.batch_dup_comps_total += int(
-                    np.sum(np.asarray(stats.batch_dup_comps)))
+                    np.sum(stats.batch_dup_comps))
+                self.lane_steps_total += int(np.sum(stats.steps))
+                # each chunk is one run of the outer loop, which iterates
+                # until its slowest lane stops (padding lanes replicate a
+                # served one)
+                self.loop_iters_total += sum(int(np.max(p[2].steps))
+                                             for p in parts)
                 if obs.metrics:
                     self._record_telemetry(stats, buckets, ms)
-                ids_np = np.asarray(ids)
                 if gt_ids is not None:
                     self._recall_sum += (
-                        recall_at_k(ids_np, gt_ids, self.cfg.k) * bsz)
+                        recall_at_k(ids, gt_ids, self.cfg.k) * bsz)
                     self._recall_n += bsz
-        return ServeResult(ids_np, np.asarray(dists), stats, ms,
-                           tuple(buckets))
+        return ServeResult(ids, dists, stats, ms, tuple(buckets))
 
     # -- observability -----------------------------------------------------
 
@@ -493,8 +514,8 @@ class AnnEngine:
         latency distribution (mean, p50/p90/p95/p99, max) — globally per
         request AND per bucket size (``bucket{b}_*`` keys), so the effect
         of batch coalescing on the tail is visible from the stats alone.
-        Per-bucket rows cover single-chunk requests only (oversize chunked
-        requests stay pipelined, see ``_run_chunk``).
+        Per-bucket rows cover single-chunk requests only (an oversize
+        request's chunks are all enqueued before one sync).
 
         Memory is bounded: latency samples land in log-bucketed sketches,
         so percentile keys are bucket-resolved (exact within
@@ -521,6 +542,8 @@ class AnnEngine:
             "batch_dup_ratio": (
                 self.batch_dup_comps_total / self.dist_comps_total
                 if self.dist_comps_total else 0.0),
+            "lane_steps_total": float(self.lane_steps_total),
+            "loop_iters_total": float(self.loop_iters_total),
         }
         if self._latency_hist.count:
             out.update(self._hist_summary(self._latency_hist, "latency_"))

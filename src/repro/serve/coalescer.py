@@ -197,6 +197,7 @@ class AsyncAnnEngine:
         self._seq = itertools.count()
         self._closed = False
         self._inflight = 0       # flushes past batch pick-up, pre-resolve
+        self._batch_no = 0       # sequence number of the next batch formed
         # observability — distributions live in bounded log-bucketed
         # sketches (constant memory under sustained traffic, mergeable)
         self.submitted = 0
@@ -232,6 +233,11 @@ class AsyncAnnEngine:
         cache attached, a hit resolves the future before any of that — a
         replay is never queued, never shed, and costs no engine work.
         """
+        with self.obs.span("coalescer.submit", cat="coalescer"):
+            return self._submit(query, deadline_ms, priority)
+
+    def _submit(self, query, deadline_ms: Optional[float],
+                priority: str) -> Future:
         q = np.asarray(query, np.float32)
         if q.ndim == 2 and q.shape[0] == 1:
             q = q[0]
@@ -319,33 +325,47 @@ class AsyncAnnEngine:
 
     def _dispatch_loop(self):
         self.obs.tracer.name_thread("coalescer-dispatch")
+        while self.wait_due():
+            self._flush_once()
+
+    def wait_due(self) -> bool:
+        """Block until the policy calls for a flush (see
+        :meth:`_due_locked`); False once the engine is closed and drained.
+        The dispatcher thread's wait, in two spans: ``coalescer.idle``
+        while the queue is empty, ``coalescer.fill`` while the oldest
+        request waits out ``max_wait_ms`` for the batch to fill (new
+        arrivals re-notify; a full queue makes it a check, not a wait).
+        Each span opens before the lock is taken, so the thread is in a
+        span throughout.  The condition waits are real time."""
         max_wait_s = self.policy.max_wait_ms / 1e3
         while True:
-            with self._lock:
-                while not self._pending and not self._closed:
-                    self._lock.wait()
-                if self._closed and not self._pending:
-                    return
-                # flush when full, else sleep out the oldest request's
-                # remaining wait budget (new arrivals re-notify)
-                now = self._clock()
-                if (len(self._pending) < self.policy.max_batch
-                        and self._oldest_age_s(now) < max_wait_s
-                        and not self._closed):
-                    self._lock.wait(max_wait_s - self._oldest_age_s(now))
-                    continue
-            self._flush_once()
+            idle = not self._pending   # the span's name; decided below
+            with self.obs.span("coalescer.idle" if idle else "coalescer.fill",
+                               cat="coalescer", batch=self._batch_no):
+                with self._lock:
+                    while True:
+                        if not self._pending:
+                            if self._closed:
+                                return False
+                            if not idle:
+                                break           # emptied: an idle span
+                            self._lock.wait()
+                            continue
+                        now = self._clock()
+                        if self._due_locked(now):
+                            return True
+                        if idle:
+                            break               # a request came: fill
+                        self._lock.wait(max_wait_s - self._oldest_age_s(now))
 
     def flush(self) -> int:
         """Synchronously dispatch pending requests (one batch per call
         until the queue is empty); returns the number of requests resolved.
         The deterministic path for ``start=False`` engines and tests."""
         n = 0
-        while True:
-            served = self._flush_once()
-            if served == 0:
-                return n
-            n += served
+        while self._pending:
+            n += self._flush_once()
+        return n
 
     def _due_locked(self, now: float) -> bool:
         """True when the policy calls for a flush at ``now`` (lock held):
@@ -395,26 +415,37 @@ class AsyncAnnEngine:
         return resolved
 
     def _flush_once(self) -> int:
-        with self._lock:
-            if not self._pending:
-                return 0
-            # committed: from here until the finally, close(drain=True)
-            # must wait — the batch leaves _pending BEFORE its futures
-            # resolve, so "queue empty" alone does not mean "drained"
-            self._inflight += 1
-        try:
-            return self._flush_committed()
-        finally:
+        """One batch, in a ``coalescer.batch`` span that holds the batch's
+        ``coalescer.form``, ``engine.search`` and ``coalescer.resolve``."""
+        with self.obs.span("coalescer.batch", cat="coalescer") as sp:
             with self._lock:
-                self._inflight -= 1
-                self._lock.notify_all()
+                if not self._pending:
+                    return 0
+                # committed: from here until the finally, close(drain=True)
+                # must wait — the batch leaves _pending BEFORE its futures
+                # resolve, so "queue empty" alone does not mean "drained"
+                self._inflight += 1
+                batch_no = self._batch_no
+                self._batch_no += 1
+            sp.add_args(batch=batch_no)
+            try:
+                # every span of this batch, the engine's included, carries
+                # its number
+                with self.obs.tags(batch=batch_no):
+                    return self._flush_committed()
+            finally:
+                with self._lock:
+                    self._inflight -= 1
+                    self._lock.notify_all()
 
     def _flush_committed(self) -> int:
-        tracer = self.obs.tracer
+        """Form, search and resolve one batch."""
+        obs = self.obs
+        tracer = obs.tracer
         resolved = 0
         n_shed = n_cancelled = 0
         live: List[_Pending] = []
-        with tracer.span("batch_formation", cat="coalescer") as sp:
+        with obs.span("coalescer.form", cat="coalescer") as sp:
             with self._lock:
                 if not self._pending:
                     return 0   # drained by a concurrent flush
@@ -425,7 +456,7 @@ class AsyncAnnEngine:
                 self._pending = rest
             # the EDF decision, as the trace records it: who was picked, in
             # what order, who was shed, who stays queued
-            sp.add_args(pending=n_pending, batch=len(batch),
+            sp.add_args(pending=n_pending, size=len(batch),
                         shed=len(expired), deferred=len(rest),
                         edf_order=[p.seq for p in batch])
             # set_running_or_notify_cancel guards every resolution: a future
@@ -462,32 +493,27 @@ class AsyncAnnEngine:
                     n_cancelled += 1
                     tracer.async_end("request", p.seq,
                                      args={"outcome": "cancelled"})
-        if self.obs.metrics and (n_shed or n_cancelled):
-            out = self.obs.registry.counter(
-                "coalescer_requests_total", "requests by final outcome")
-            if n_shed:
-                out.inc(n_shed, outcome="shed")
-            if n_cancelled:
-                out.inc(n_cancelled, outcome="cancelled")
+            if obs.metrics and (n_shed or n_cancelled):
+                out = obs.registry.counter(
+                    "coalescer_requests_total", "requests by final outcome")
+                if n_shed:
+                    out.inc(n_shed, outcome="shed")
+                if n_cancelled:
+                    out.inc(n_cancelled, outcome="cancelled")
+            if live:
+                queries = np.stack([p.query for p in live])
         if not live:
             return resolved
-        queries = np.stack([p.query for p in live])
-        # engine.search runs inside this span on the same thread, so its
-        # engine.search/device_compute spans nest under dispatch by
-        # containment
-        with tracer.span("dispatch", cat="coalescer",
-                         args={"batch": len(live)}):
-            try:
-                res = self.engine.search(queries)
-            except Exception as e:  # noqa: BLE001 - failure goes to callers
-                for p in live:
-                    tracer.async_end("request", p.seq,
-                                     args={"outcome": "error"})
-                    p.future.set_exception(e)
-                return resolved + len(live)
+        try:
+            res = self.engine.search(queries)
+        except Exception as e:  # noqa: BLE001 - failure goes to callers
+            for p in live:
+                tracer.async_end("request", p.seq,
+                                 args={"outcome": "error"})
+                p.future.set_exception(e)
+            return resolved + len(live)
         done_t = self._clock()
-        with tracer.span("resolve", cat="coalescer",
-                         args={"batch": len(live)}):
+        with obs.span("coalescer.resolve", cat="coalescer", size=len(live)):
             with self._lock:
                 self.batches_dispatched += 1
                 self._batch_size_hist.observe(len(live))
@@ -495,8 +521,8 @@ class AsyncAnnEngine:
                 waits = [(now - p.enqueue_t) * 1e3 for p in live]
                 for w in waits:
                     self._queue_wait_hist.observe(w)
-            if self.obs.metrics:
-                reg = self.obs.registry
+            if obs.metrics:
+                reg = obs.registry
                 reg.counter("coalescer_requests_total",
                             "requests by final outcome"
                             ).inc(len(live), outcome="served")

@@ -24,7 +24,7 @@ import pytest
 from repro.ann import AnnIndex, IndexSpec, SearchParams
 from repro.data import make_vector_dataset
 from repro.obs import (NULL_OBS, NULL_TRACER, LogHistogram, MetricsRegistry,
-                       Observability, TraceRecorder, device_annotation)
+                       Observability, TraceRecorder)
 
 ROOT = Path(__file__).resolve().parents[1]
 
@@ -256,12 +256,86 @@ def test_null_tracer_is_shared_noop():
     assert NULL_TRACER.n_events == 0
 
 
-def test_device_annotation_smoke():
-    # enabled=False must not even resolve jax.profiler
-    with device_annotation("x", enabled=False):
+def test_device_annotation_smoke(tmp_path):
+    # the profiler sink: with no profiler session running, a profile-only
+    # span is the shared null span; under a session it records
+    obs = Observability(tracing=False, metrics=False, profile=True)
+    assert obs.span("x") is obs.span("y")
+    with obs.span("engine.dispatch", cat="engine", bucket=8) as sp:
+        sp.add_args(pad=1)
+    events = _profiled(tmp_path, lambda: _nested_spans(obs))
+    assert [e.name for e in events] == ["outer.span", "inner.span"]
+
+
+# -- the one span API: Observability.span -------------------------------------
+
+def _nested_spans(obs):
+    with obs.tags(batch=5):
+        with obs.span("outer.span", cat="t", size=3) as sp:
+            with obs.span("inner.span", cat="t"):
+                pass
+            sp.add_args(bytes=64)
+
+
+def _profiled(tmp_path, fn):
+    """Run ``fn`` under a jax.profiler session on this host; return the
+    ``*.span`` events of the captured host plane, in start order."""
+    import jax
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    pd = ProfileData.from_file(str(path))
+    events = [e for plane in pd.planes if plane.name.startswith("/host:")
+              for line in plane.lines for e in line.events
+              if e.name.endswith(".span")]
+    return sorted(events, key=lambda e: e.start_ns)
+
+
+@pytest.mark.parametrize("tracing,profile", [(False, False), (True, False),
+                                             (False, True), (True, True)])
+def test_span_sinks(tmp_path, tracing, profile):
+    obs = Observability(tracing=tracing, metrics=False, profile=profile)
+    if not (tracing or profile):
+        # neither sink: the shared null span, no allocation, nothing kept
+        assert obs.span("a", x=1) is obs.span("b")
+        assert obs.tags(batch=1) is obs.tags(batch=2)
+    events = _profiled(tmp_path, lambda: _nested_spans(obs))
+    recorded = [e for e in obs.tracer.events() if e["ph"] == "X"]
+    # the recorder sink: what tracing records, and only then
+    assert [e["name"] for e in recorded] == (
+        ["inner.span", "outer.span"] if tracing else [])
+    # the profiler sink: what a jax.profiler session sees, and only then
+    assert [e.name for e in events] == (
+        ["outer.span", "inner.span"] if profile else [])
+    if profile:
+        outer, inner = events
+        assert dict(outer.stats) == {"batch": 5, "size": 3, "bytes": 64}
+        assert dict(inner.stats) == {"batch": 5}
+        assert outer.start_ns <= inner.start_ns
+        assert inner.end_ns <= outer.end_ns
+
+
+def test_span_args_tags_and_nesting():
+    obs = Observability(tracing=True, metrics=False)
+    _nested_spans(obs)
+    with obs.span("after.span", cat="t"):
         pass
-    with device_annotation("ann_dispatch/bucket8", enabled=True):
-        pass  # nullcontext fallback when the profiler is unavailable
+    ev = {e["name"]: e for e in obs.tracer.events()}
+    outer, inner = ev["outer.span"], ev["inner.span"]
+    assert outer["args"] == {"batch": 5, "size": 3, "bytes": 64}
+    assert outer["cat"] == "t"
+    assert inner["args"] == {"batch": 5}
+    assert outer["ts"] <= inner["ts"]
+    assert inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"] + 0.5
+    # tags end with their block
+    assert ev["after.span"]["args"] == {}
+    ct = _load_check_trace()
+    assert ct.validate(obs.tracer.to_chrome_trace(),
+                       require=["outer.span", "inner.span"]) == []
 
 
 # -- engine + coalescer integration ------------------------------------------
@@ -273,11 +347,17 @@ def test_engine_search_records_spans_metrics_and_telemetry(ds, index):
     assert res.ids.shape[0] == 3
 
     names = [e["name"] for e in obs.tracer.events()]
-    assert "engine.search" in names
-    assert "device_compute" in names and "postprocess" in names
+    assert names == ["engine.pad", "engine.dispatch", "engine.sync",
+                     "engine.readback", "engine.postprocess",
+                     "engine.search"]   # children close first
+    ev = {e["name"]: e for e in obs.tracer.events()}
+    assert ev["engine.pad"]["args"] == {"bucket": 4, "pad": 1}
+    # one chunk: its ids, dists and SearchStats in ONE device->host read
+    assert ev["engine.readback"]["args"]["arrays"] == 1
+    assert ev["engine.readback"]["args"]["bytes"] == 4 * 4 * (2 * 10 + 8)
     ct = _load_check_trace()
     assert ct.validate(obs.tracer.to_chrome_trace(),
-                       require=["engine.search", "device_compute"]) == []
+                       require=["engine.search", "engine.dispatch"]) == []
 
     d = obs.registry.to_dict()
     # convergence telemetry: one per-lane histogram per SearchStats leaf
@@ -299,7 +379,7 @@ def test_engine_stats_schema_bounded_memory_and_key_order(ds, index):
     head = ["queries_served", "requests_served", "padded_queries",
             "jit_cache_size", "cache_hits", "cache_misses",
             "dist_comps_total", "uniq_comps_total", "batch_dup_comps_total",
-            "batch_dup_ratio"]
+            "batch_dup_ratio", "lane_steps_total", "loop_iters_total"]
     assert keys[:len(head)] == head
     lat = ["latency_mean_ms", "latency_p50_ms", "latency_p90_ms",
            "latency_p95_ms", "latency_p99_ms", "latency_max_ms"]
@@ -351,16 +431,22 @@ def test_coalesced_span_tree_under_manual_flush(ds, index):
     trace = obs.tracer.to_chrome_trace()
     ct = _load_check_trace()
     assert ct.validate(trace, require=[
-        "batch_formation", "dispatch", "engine.search", "device_compute",
-        "resolve", "request"]) == []
+        "coalescer.submit", "coalescer.form", "engine.search",
+        "engine.dispatch", "coalescer.resolve", "request"]) == []
     ev = trace["traceEvents"]
-    # one coalesced batch: dispatch contains engine.search by containment
-    disp = next(e for e in ev if e["name"] == "dispatch")
+    # one coalesced batch: form, then engine.search containing the
+    # dispatch, then resolve, all tagged with the batch's number
+    form = next(e for e in ev if e["name"] == "coalescer.form")
     srch = next(e for e in ev if e["name"] == "engine.search")
-    assert disp["ts"] <= srch["ts"]
-    assert srch["ts"] + srch["dur"] <= disp["ts"] + disp["dur"] + 0.5
-    form = next(e for e in ev if e["name"] == "batch_formation")
-    assert form["args"]["batch"] == 3
+    disp = next(e for e in ev if e["name"] == "engine.dispatch")
+    res = next(e for e in ev if e["name"] == "coalescer.resolve")
+    assert form["ts"] + form["dur"] <= srch["ts"] + 0.5
+    assert srch["ts"] <= disp["ts"]
+    assert disp["ts"] + disp["dur"] <= srch["ts"] + srch["dur"] + 0.5
+    assert srch["ts"] + srch["dur"] <= res["ts"] + 0.5
+    assert form["args"]["size"] == 3
+    assert form["args"]["batch"] == disp["args"]["batch"] \
+        == res["args"]["batch"] == 0
     assert sorted(form["args"]["edf_order"]) == [0, 1, 2]
     # every submitted request has a paired b/e lifeline ending "served"
     begins = [e for e in ev if e["ph"] == "b" and e["name"] == "request"]
@@ -400,3 +486,61 @@ def test_deadline_shed_emits_span_event_and_counter(ds, index):
     shed = [s for s in d["coalescer_requests_total"]["series"]
             if s["labels"] == {"outcome": "shed"}]
     assert shed[0]["value"] == 1.0
+
+
+# -- spans of one batch, and the engine's lane counters ------------------------
+
+def test_one_batch_spans_in_order_under_pump(ds, index):
+    import threading
+    obs = Observability(tracing=True, metrics=False)
+    srv = index.serve_async(PARAMS, start=False, bucket_sizes=BUCKETS,
+                            obs=obs, max_batch=8, max_wait_ms=5.0)
+    # the dispatcher's wait, driven by hand: idle until a request arrives,
+    # then fill until max_wait_ms has passed
+    late = threading.Timer(0.02, lambda: srv.submit(ds.queries[0]))
+    late.start()
+    assert srv.wait_due()
+    late.join()
+    assert srv.pump() == 1
+    srv.close()
+    assert srv.wait_due() is False
+    ev = sorted((e for e in obs.tracer.events()
+                 if e["ph"] == "X" and e["name"] != "coalescer.submit"),
+                key=lambda e: e["ts"])
+    # the wait after close: an idle span before the next batch, number 1
+    assert ev[-1]["name"] == "coalescer.idle"
+    assert ev.pop()["args"]["batch"] == 1
+    assert [e["name"] for e in ev] == [
+        "coalescer.idle", "coalescer.fill", "coalescer.batch",
+        "coalescer.form", "engine.search", "engine.pad", "engine.dispatch",
+        "engine.sync", "engine.readback", "engine.postprocess",
+        "coalescer.resolve"]
+    assert {e["args"]["batch"] for e in ev} == {0}
+    assert ev[1]["dur"] >= 0.5 * 5e3   # the fill span waits out max_wait
+    # the batch span holds the rest of the batch's spans
+    assert all(ev[2]["ts"] <= e["ts"] and e["ts"] + e["dur"]
+               <= ev[2]["ts"] + ev[2]["dur"] + 0.5 for e in ev[3:])
+    ct = _load_check_trace()
+    assert ct.validate(obs.tracer.to_chrome_trace(),
+                       require=["coalescer.submit"]) == []
+
+
+def test_engine_lane_counters_are_sums_of_steps(ds, index):
+    obs = Observability(tracing=True, metrics=False)
+    engine = index.serve(PARAMS, bucket_sizes=BUCKETS, obs=obs)
+    top = BUCKETS[-1]
+    steps = []
+    for size in (3, 8, 11):     # 11 > the top bucket: two chunks, 8 + 3
+        res = engine.search(ds.queries[:size])
+        assert isinstance(res.stats.steps, np.ndarray)
+        steps.append(res.stats.steps)
+    st = engine.stats()
+    assert st["lane_steps_total"] == sum(int(s.sum()) for s in steps)
+    chunks = [c for s in steps for c in np.split(s, range(top, len(s), top))]
+    assert len(chunks) == 4
+    assert st["loop_iters_total"] == sum(int(c.max()) for c in chunks)
+    assert 0 < st["lane_steps_total"] <= top * st["loop_iters_total"]
+    # the two-chunk request still reads each chunk back once
+    reads = [e["args"]["arrays"] for e in obs.tracer.events()
+             if e["name"] == "engine.readback"]
+    assert reads == [1, 1, 2]
