@@ -364,7 +364,8 @@ def search_topm_batch_visited(
             f"(B, N) mask IS the visited set); got {cfg.visited_mode!r}")
     st = _run_topm_batch(graph, queries, cfg, start, dist_fn)
     ids, dists = fq.results_batch(st.frontier, cfg.k)
-    return ids, dists, st.stats, st.visited.table
+    # the traversal keeps one bit per vertex; the mask exists only here
+    return ids, dists, st.stats, vs.unpack(st.visited, graph.n_nodes)
 
 
 def search_topm(

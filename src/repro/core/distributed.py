@@ -96,8 +96,9 @@ def _merge_all_walkers(local: fq.Frontier, axis: str) -> fq.Frontier:
 def _reduce_visited(v: vs.Visited, axis: str) -> vs.Visited:
     """§4.4 eventual consistency across devices at a sync point."""
     if v.mode_bitmap:
-        table = jax.lax.pmax(v.table.astype(jnp.uint8), axis) > 0
-        return v._replace(table=table)
+        # a max of packed words is not their OR: gather and OR-fold them
+        words = jax.lax.all_gather(v.table, axis)      # (W, words)
+        return v._replace(table=vs.or_reduce(words))
     if v.mask == 0:
         return v
     tables = jax.lax.all_gather(v.table, axis)        # (W, size)

@@ -4,11 +4,17 @@ Three modes, trading exactness for scale, all with the paper's correctness
 model: a false-negative lookup merely causes a duplicate distance computation
 (benign — the queue merge dedups); a false *positive* is never produced.
 
-* ``bitmap`` — exact dense boolean array over the N graph vertices.  Per
-  walker, per query.  The paper's shared CPU bitvector with benign races maps
-  to *per-walker* maps that are OR-merged only at global syncs ("eventual
-  consistency"); between syncs walkers may duplicate each other's work, which
-  we measure (paper claims <5%).
+* ``bitmap`` — exact dense bitmap over the N graph vertices, one bit per
+  vertex in ceil(N/32) uint32 words: bit ``id & 31`` of word ``id >> 5``
+  marks vertex ``id``.  Per walker, per query.  The paper's shared CPU
+  bitvector with benign races maps to *per-walker* maps that are OR-merged
+  only at global syncs ("eventual consistency"); between syncs walkers may
+  duplicate each other's work, which we measure (paper claims <5%).  Bits,
+  not bools, because every round passes over whole maps (the scatter, the
+  walker broadcast and merge, the loop carry): a bit moves 1/8 of a bool's
+  bytes.  A (…, N) bool view exists only where a caller needs one
+  (:func:`unpack`: the build's prune pool, through
+  ``bfis.search_topm_batch_visited``).
 * ``hash``  — fixed 2**bits open-addressed set with bounded linear probing.
   Scales to billion-node graphs (memory independent of N).  Probe losses and
   in-batch scatter races cause duplicate computations only (benign, and the
@@ -29,7 +35,8 @@ import jax.numpy as jnp
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class Visited:
-    table: jax.Array    # bitmap: (N,) bool   | hash: (2**bits,) int32 keys
+    # bitmap: (ceil(N/32),) uint32 words | hash: (2**bits,) int32 keys
+    table: jax.Array
     mode_bitmap: bool = dataclasses.field(metadata=dict(static=True))
     mask: int = dataclasses.field(metadata=dict(static=True))  # hash: 2**b - 1
 
@@ -39,11 +46,16 @@ class Visited:
 
 _EMPTY = jnp.int32(-1)
 _PROBES = 8
+_WORD = 32   # vertices per bitmap word
+
+
+def _n_words(n_nodes: int) -> int:
+    return -(-n_nodes // _WORD)
 
 
 def make_visited(mode: str, n_nodes: int, hash_bits: int = 14) -> Visited:
     if mode == "bitmap":
-        return Visited(jnp.zeros((n_nodes,), bool), True, 0)
+        return Visited(jnp.zeros((_n_words(n_nodes),), jnp.uint32), True, 0)
     if mode == "hash":
         size = 1 << hash_bits
         return Visited(jnp.full((size,), _EMPTY, jnp.int32), False, size - 1)
@@ -67,16 +79,20 @@ def check_and_insert(
     marked; those are the ids whose distances must be computed this step.
     """
     if v.mode_bitmap:
-        n = v.table.shape[0]
-        safe = jnp.clip(ids, 0, n - 1)
-        already = v.table[safe] & valid
+        # valid ids are < N (callers mask the graph's padding sentinel), so
+        # no bit past N in the last word is ever set
+        safe = jnp.clip(ids, 0, v.table.shape[0] * _WORD - 1)
+        word = safe >> 5
+        bit = (safe & (_WORD - 1)).astype(jnp.uint32)
+        already = ((v.table[word] >> bit) & 1).astype(bool) & valid
         fresh = valid & ~already
         # in-batch duplicates: keep first occurrence only (exact dedup)
         fresh = fresh & _first_occurrence(ids, fresh)
-        # scatter-max (commutative OR): duplicate indices in the batch must
-        # not be able to erase a concurrent True write (.set is order-
-        # nondeterministic with duplicates)
-        table = v.table.at[safe].max(fresh)
+        # scatter-ADD of the fresh bits is an exact OR: the fresh ids of one
+        # call are distinct (first occurrence) and their bits were unset
+        # (~already), so ids that share a word add disjoint bits and no
+        # carry can occur; stale and invalid lanes add 0
+        table = v.table.at[word].add(fresh.astype(jnp.uint32) << bit)
         return v._replace(table=table), fresh
 
     if v.mask == 0:  # loose mode: no memory; only in-batch dedup
@@ -132,7 +148,8 @@ def make_visited_batch(mode: str, n_nodes: int, batch: int,
     through repeated broadcasting at the call site; this helper only adds
     the query axis."""
     if mode == "bitmap":
-        return Visited(jnp.zeros((batch, n_nodes), bool), True, 0)
+        return Visited(jnp.zeros((batch, _n_words(n_nodes)), jnp.uint32),
+                       True, 0)
     if mode == "hash":
         size = 1 << hash_bits
         return Visited(jnp.full((batch, size), _EMPTY, jnp.int32), False,
@@ -160,7 +177,7 @@ def popcount(v: Visited) -> jax.Array:
     """
     t0 = v.table[0] if v.table.ndim > 1 else v.table
     if v.mode_bitmap:
-        return jnp.sum(t0).astype(jnp.int32)
+        return jnp.sum(jax.lax.population_count(t0)).astype(jnp.int32)
     if v.mask == 0:
         return jnp.int32(0)
     return jnp.sum(t0 != _EMPTY).astype(jnp.int32)
@@ -173,8 +190,7 @@ def merge_visited(vs: Visited) -> Visited:
     non-empty keys (best effort; losses are benign).  Loose: no-op.
     """
     if vs.mode_bitmap:
-        merged = jnp.any(vs.table, axis=0)
-        w = vs.table.shape[0]
+        merged = or_reduce(vs.table)
         return Visited(jnp.broadcast_to(merged, vs.table.shape), True, 0)
     if vs.mask == 0:
         return vs
@@ -184,3 +200,17 @@ def merge_visited(vs: Visited) -> Visited:
         return jnp.where(take, t, acc), None
     merged, _ = jax.lax.scan(fold, vs.table[0], vs.table[1:])
     return Visited(jnp.broadcast_to(merged, vs.table.shape), False, vs.mask)
+
+
+def or_reduce(words: jax.Array) -> jax.Array:
+    """Bitwise OR of stacked bitmap words over the leading axis: the union
+    of the maps."""
+    return jax.lax.reduce(words, jnp.uint32(0), jax.lax.bitwise_or, (0,))
+
+
+def unpack(v: Visited, n_nodes: int) -> jax.Array:
+    """A bitmap map as a (..., N) bool mask over the graph's vertices."""
+    shifts = jnp.arange(_WORD, dtype=jnp.uint32)
+    bits = (v.table[..., None] >> shifts) & 1           # (..., words, 32)
+    flat = bits.reshape(v.table.shape[:-1] + (-1,))
+    return flat[..., :n_nodes].astype(bool)

@@ -1,4 +1,5 @@
 """Hypothesis property tests on the system's invariants."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -8,6 +9,7 @@ from hypothesis import given, settings, strategies as st  # noqa: E402
 
 from repro.core import queue as fq
 from repro.core import visited as vs
+from repro.core.distributed import _reduce_visited
 from repro.core.metrics import batch_unique_counts, recall_at_k
 from repro.kernels.dedup import dedupdist, unique_ids_inverse
 from repro.kernels.l2dist import l2dist_rowgather
@@ -126,6 +128,51 @@ def test_visited_never_false_positive(seed, mode):
             v2, fresh2 = vs.check_and_insert(
                 v, ids, jnp.ones((len(seen),), bool))
             assert not np.asarray(fresh2).any()
+
+
+@pytest.mark.parametrize("n", [500, 513, 512])
+@given(seed=st.integers(0, 10_000))
+@settings(max_examples=10, deadline=None)
+def test_bitmap_matches_set_model(n, seed):
+    """The packed bitmap (one bit per vertex in uint32 words) against a
+    Python set: ``fresh`` exactly, the unpacked table and ``popcount`` after
+    every call, and ``merge_visited`` and the walker-sharded path's
+    cross-device OR as the union of walkers' maps.  Half the ids fall in
+    the last (partial, for N not a multiple of 32) word."""
+    rng = np.random.RandomState(seed)
+    walkers = []
+    for _ in range(3):
+        v = vs.make_visited("bitmap", n)
+        assert v.table.dtype == jnp.uint32 and v.table.shape == (-(-n // 32),)
+        seen = set()
+        for _ in range(5):
+            ids = np.where(rng.rand(12) < 0.5, rng.randint(0, n, 12),
+                           rng.randint(max(n - 40, 0), n, 12)).astype(np.int32)
+            valid = rng.rand(12) > 0.2
+            v, fresh = vs.check_and_insert(v, jnp.asarray(ids),
+                                           jnp.asarray(valid))
+            want = []
+            for id_, ok in zip(ids.tolist(), valid.tolist()):
+                want.append(ok and id_ not in seen)
+                if ok:
+                    seen.add(id_)
+            np.testing.assert_array_equal(np.asarray(fresh), want)
+            mask = np.asarray(vs.unpack(v, n))
+            assert set(np.flatnonzero(mask).tolist()) == seen
+            # no bit past N in the last word
+            assert int(vs.popcount(v)) == len(seen) == mask.sum()
+        walkers.append((v, seen))
+    stacked = vs.Visited(jnp.stack([v.table for v, _ in walkers]), True, 0)
+    merged = vs.merge_visited(stacked)
+    union = set().union(*(s for _, s in walkers))
+    # the walker-sharded path ORs the walkers' maps across a mesh axis
+    reduced = jax.vmap(
+        lambda t: _reduce_visited(vs.Visited(t, True, 0), "w").table,
+        axis_name="w")(stacked.table)
+    for table in (merged.table, reduced):
+        for row in np.asarray(vs.unpack(vs.Visited(table, True, 0), n)):
+            assert set(np.flatnonzero(row).tolist()) == union
+    assert int(vs.popcount(merged)) == len(union)
 
 
 @given(seed=st.integers(0, 10_000),
